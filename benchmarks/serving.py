@@ -18,9 +18,9 @@ Two measurements on one fitted suite:
   PYTHONPATH=src python -m benchmarks.serving            # FAST suite
   PYTHONPATH=src python -m benchmarks.serving --smoke    # tiny, seconds
 
-Run as a script the process forces 4 host devices, so the 2/4-shard rows
-execute under shard_map on a real device mesh; under ``benchmarks.run``
-(single-device process) they use logical shards with identical semantics.
+Multi-shard rows use logical shards (identical semantics on one device)
+unless ``--mesh`` asks for a device mesh, which then needs that many
+devices; on the CPU backend ``--mesh`` forces 4 host devices.
 """
 from __future__ import annotations
 
@@ -29,6 +29,8 @@ import asyncio
 import json
 import os
 import time
+
+from repro.common.compile_cache import place_compile_cache
 
 DEFAULT_SHARDS = (1, 2, 4)
 DEFAULT_DEADLINE = 5.0  # seconds — generous; the report must show 0 timeouts
@@ -94,15 +96,30 @@ def run_sync_compare(suite, stream, gts, *, batch_size: int = 32) -> dict:
     }
 
 
+def _data_mesh(shards: int):
+    """A 1-D ("data",) mesh over the first ``shards`` devices; too few
+    devices is an error, never a silent fall back to logical shards."""
+    import numpy as np
+
+    import jax
+    from jax.sharding import Mesh
+
+    if jax.device_count() < shards:
+        raise RuntimeError(f"a {shards}-shard mesh needs {shards} devices, "
+                           f"found {jax.device_count()}")
+    return Mesh(np.array(jax.devices()[:shards]), ("data",))
+
+
 def run_async_shards(suite, stream, gts, *, batch_size: int = 32,
+                     use_mesh: bool = False,
                      shards=DEFAULT_SHARDS, rate: float = DEFAULT_RATE,
                      max_wait: float = 0.01,
                      deadline: float = DEFAULT_DEADLINE, seed: int = 0
                      ) -> list[dict]:
-    """Poisson open-loop arrivals into AsyncServingEngine per shard count."""
+    """Poisson open-loop arrivals into AsyncServingEngine per shard count.
+    ``use_mesh`` serves every multi-shard row over a device mesh (an error
+    when there are too few devices); otherwise shards are logical."""
     import numpy as np
-
-    import jax
 
     from repro.serve.batch import warm_bucket_ladder
     from repro.serve.queue import AsyncServingEngine, serve_stream
@@ -114,13 +131,11 @@ def run_async_shards(suite, stream, gts, *, batch_size: int = 32,
     try:
         for s in shards:
             mesh = None
-            if s > 1:
-                if jax.device_count() >= s and suite.table.n_rows % s == 0:
-                    from jax.sharding import Mesh
-                    mesh = Mesh(np.array(jax.devices()[:s]), ("data",))
-                    bq.bind_shards(mesh=mesh)
-                else:
-                    bq.bind_shards(s)  # logical shards, same semantics
+            if s > 1 and use_mesh:
+                mesh = _data_mesh(s)
+                bq.bind_shards(s, mesh=mesh)
+            elif s > 1:
+                bq.bind_shards(s)  # logical shards, same semantics
             else:
                 bq.bind_shards()  # plan-driven single-shard baseline
             warm_bucket_ladder(bq.execute_batch, stream, batch_size)
@@ -193,8 +208,6 @@ def run_sharded(dataset: str = "sift", rows: int = 500_000, shards: int = 4,
     ``use_mesh=True`` (CLI ``--mesh``) forces the mesh anyway."""
     import numpy as np
 
-    import jax
-
     from repro.bench import datasets, queries
     from repro.core.executor import recall_at_k
     from repro.core.query import ExecutionPlan, SubqueryParams
@@ -220,11 +233,7 @@ def run_sharded(dataset: str = "sift", rows: int = 500_000, shards: int = 4,
         SubqueryParams(k_mult=k_mult, nprobe=nprobe, max_scan=max_scan,
                        iterative=True) for _ in range(n_vec)))
 
-    mesh = None
-    if use_mesh and jax.device_count() >= shards \
-            and table.n_rows % shards == 0:
-        from jax.sharding import Mesh
-        mesh = Mesh(np.array(jax.devices()[:shards]), ("data",))
+    mesh = _data_mesh(shards) if use_mesh else None
 
     def make_bx(s, cm=None):
         kw = {} if s <= 1 else (
@@ -951,8 +960,8 @@ def run_graph(*, rows: int = 100_000, n_hard: int = 48, batch_size: int = 16,
 
 def run(sizes=None, dataset: str = "part", *, n_stream: int = 64,
         batch_size: int = 32, seed: int = 0, shards=DEFAULT_SHARDS,
-        rate: float = DEFAULT_RATE, deadline: float = DEFAULT_DEADLINE
-        ) -> dict:
+        rate: float = DEFAULT_RATE, deadline: float = DEFAULT_DEADLINE,
+        use_mesh: bool = False) -> dict:
     from benchmarks import common
 
     sizes = common.FAST if sizes is None else sizes
@@ -967,7 +976,7 @@ def run(sizes=None, dataset: str = "part", *, n_stream: int = 64,
     out.update(run_sync_compare(suite, stream, gts, batch_size=batch_size))
     out["async_shards"] = run_async_shards(
         suite, stream, gts, batch_size=batch_size, shards=shards, rate=rate,
-        deadline=deadline, seed=seed)
+        deadline=deadline, seed=seed, use_mesh=use_mesh)
     return out
 
 
@@ -1011,12 +1020,14 @@ def main():
     ap.add_argument("--shards", type=int, default=4,
                     help="shard count for --sharded")
     ap.add_argument("--mesh", action="store_true",
-                    help="force a host-platform device mesh for --sharded "
-                         "(default: logical shards — a fake mesh on one "
-                         "physical CPU measures the partitioner, not the "
-                         "algorithm)")
+                    help="serve the multi-shard rows over a device mesh; "
+                         "on the CPU backend (JAX_PLATFORMS=cpu) host "
+                         "devices are forced for it (default: logical "
+                         "shards — a fake mesh on one physical CPU measures "
+                         "the partitioner, not the algorithm)")
     ap.add_argument("--out", default=None)
     args = ap.parse_args()
+    place_compile_cache()
 
     if args.crossover:
         res = {"figure": "serving_scoring_crossover",
@@ -1048,12 +1059,12 @@ def main():
                 json.dump(res, f, indent=2)
         return
 
-    # force a 4-device host platform BEFORE jax initializes so the 2/4-shard
-    # rows run under shard_map on a real mesh (imports below are lazy for
-    # exactly this reason; benchmarks.run imports this module with jax
-    # already single-device and gets logical shards instead). The sharded
-    # sweep defaults to logical shards, so it only forces under --mesh.
-    if not args.sharded or args.mesh:
+    # on the CPU backend, --mesh forces a 4-device host platform BEFORE the
+    # backend initializes so the 2/4-shard rows run under shard_map on a
+    # mesh (imports below are lazy for exactly this reason). An accelerator
+    # backend brings its own devices and is never given host devices.
+    if args.mesh and os.environ.get("JAX_PLATFORMS", "").split(",")[0] \
+            == "cpu":
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
@@ -1075,7 +1086,7 @@ def main():
         else (common.FULL if args.full else common.FAST)
     res = run(sizes, args.dataset or "part", n_stream=args.n_stream,
               batch_size=args.batch_size, rate=args.rate,
-              deadline=args.deadline)
+              deadline=args.deadline, use_mesh=args.mesh)
     if args.out:
         with open(args.out, "w") as f:
             json.dump(res, f, indent=2)

@@ -28,6 +28,7 @@ import time
 import numpy as np
 
 from repro.bench import datasets, queries
+from repro.common.compile_cache import place_compile_cache
 from repro.core.boomhq import BoomHQ, BoomHQConfig
 from repro.core.data_encoder import DataEncoderConfig
 from repro.core.executor import recall_at_k
@@ -44,6 +45,7 @@ def ground_truths(table, reqs):
 
 
 def main():
+    place_compile_cache()
     table = datasets.make("aka_title", rows=6000, seed=0)
     train = queries.gen_workload(table, 40, n_vec_used=2, seed=1)
     bq = BoomHQ(table, BoomHQConfig(

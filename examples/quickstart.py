@@ -6,6 +6,7 @@ including DNF predicates written with the builder algebra.
 import numpy as np
 
 from repro.bench import datasets, queries
+from repro.common.compile_cache import place_compile_cache
 from repro.core.boomhq import BoomHQ, BoomHQConfig
 from repro.core.data_encoder import DataEncoderConfig
 from repro.core.executor import recall_at_k
@@ -16,6 +17,7 @@ from repro.vectordb.algebra import col
 
 
 def main():
+    place_compile_cache()
     # 1. a table with two vector columns + four scalar columns (TPC-H Part
     #    shape, §4 benchmark construction)
     table = datasets.make("part", rows=4000, seed=0)

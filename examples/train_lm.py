@@ -9,6 +9,7 @@ import argparse
 import jax
 import numpy as np
 
+from repro.common.compile_cache import place_compile_cache
 from repro.configs.base import ModelConfig
 from repro.data.pipeline import BatchSpec, make_source
 
@@ -24,6 +25,7 @@ def config_100m() -> ModelConfig:
 
 
 def main():
+    place_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--ckpt", default="/tmp/repro_train_lm")

@@ -12,7 +12,7 @@ expression (the PR 1 bug class).
 
 The scope detection and taint rules are deliberately calibrated against
 this repo's idioms — ``functools.partial(kern, **static)`` bodies handed
-to ``pallas_call``, ``compat.shard_map(local, ...)`` closures over static
+to ``pallas_call``, ``jax.shard_map(local, ...)`` closures over static
 config, ``.shape``/``len()`` reads that are static under trace — so the
 repo lints clean without blanket suppressions.
 """

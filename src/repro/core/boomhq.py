@@ -83,6 +83,7 @@ class BoomHQ:
         self._fitted = False
         self.n_shards = 1  # cross-shard serving config (bind_shards)
         self.shard_mesh = None
+        self._placed = None  # (source table, its mesh-placed copy)
         self.cost_model = None  # scoring-dispatch override (bind_cost_model)
         self.tiered = None  # streaming-ingest config (bind_tiered)
         self.tenant_col = None  # namespace column index (bind_tenants)
@@ -409,15 +410,41 @@ class BoomHQ:
         operative at shard scale), the exact per-shard dense scan, or the
         plain single-device path when shards are too small to amortize the
         fan-out; filter_first groups keep the exact sharded scan. With a
-        ``mesh`` the fan-out runs under shard_map over its data axes;
-        without one, logical shards on the local device keep identical
-        semantics. ``bind_shards()`` (defaults) restores single-shard
-        serving."""
+        ``mesh`` the fan-out runs under shard_map over its data axes, and
+        the table (columns, scalars, int8 replicas) is placed on the mesh
+        here, once, row-sharded over those axes; ``n_shards`` must then be
+        1 or the mesh's shard count. Without a mesh, logical shards on the
+        local device keep identical semantics. ``bind_shards()``
+        (defaults) restores single-shard serving."""
+        axes = shard_axes if isinstance(shard_axes, tuple) else (shard_axes,)
+        if mesh is not None:
+            n_mesh = int(np.prod([mesh.shape[a] for a in axes]))
+            if int(n_shards) not in (1, n_mesh):
+                raise ValueError(f"{n_shards} shards over a {n_mesh}-way "
+                                 f"mesh")
+            n_shards = n_mesh
         self.n_shards = max(1, int(n_shards))
         self.shard_mesh = mesh
-        self.shard_axes = shard_axes
+        self.shard_axes = axes
+        self._placed = None
         self._batched = None  # rebind the executor with the new shard config
+        if mesh is not None:
+            self._serving_table()
         return self
+
+    def _serving_table(self, cold=None):
+        """The table batches execute on: a snapshot's cold epoch, else the
+        façade's table — placed on the bound mesh, and placed again
+        whenever an insert replaced it."""
+        if cold is not None:
+            return cold.table
+        if self.shard_mesh is None:
+            return self.table
+        if self._placed is None or self._placed[0] is not self.table:
+            from repro.vectordb.distributed import place_table
+            self._placed = (self.table, place_table(
+                self.table, self.shard_mesh, self.shard_axes))
+        return self._placed[1]
 
     def bind_tiered(self, hot_capacity: int = 1024, *,
                     rebuild_every: int = 0,
@@ -714,7 +741,7 @@ class BoomHQ:
         swap rebuilds once at the first post-swap batch and never
         thrashes."""
         from repro.serve.batch import BatchedHybridExecutor
-        t = self.table if cold is None else cold.table
+        t = self._serving_table(cold)
         idxs = self.indexes if cold is None else list(cold.indexes)
         hs = self.hists if cold is None else cold.hists
         grs = self.graphs if cold is None else cold.graphs

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.common import nn
-from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
+from repro.train.optimizer import AdamWConfig, adamw_init, adamw_step
 from repro.vectordb.predicates import PredicateLike, soft_encode, value_encode
 from repro.vectordb.table import Table
 
@@ -113,6 +113,28 @@ class DataEncoder:
         z = nn.mlp_apply(params["ae_enc"], e)
         return nn.mlp_apply(params["ae_dec"], z)
 
+    def _ae_loss(self, train_params, frozen_logits: tuple, vecs: tuple,
+                 es: jax.Array):
+        """Autoencoder reconstruction loss of one batch: the trainable nets
+        and the AE are trained; the frozen predictors' logits come in
+        precomputed (per column (batch, M, B)), so the frozen nets stay out
+        of the differentiated program."""
+        loss = 0.0
+        for i in range(len(self.vec_dims)):
+            fr = jax.nn.softmax(frozen_logits[i], axis=-1)
+            tr = nn.mlp_apply(train_params["trainable"][i], vecs[i])
+            e = jnp.concatenate([fr.reshape(fr.shape[0], -1), tr, es], axis=-1)
+            loss = loss + jnp.mean(jnp.square(self._ae(train_params, e) - e))
+        return loss / len(self.vec_dims)
+
+    @staticmethod
+    def _frozen_batch_logits(frozen, vecs: tuple) -> tuple:
+        """Per column, the frozen predictors' (batch, M, B) logits. The
+        softmax is left to ``_ae_loss``: at a 512-row batch, a softmax
+        fused after these batched contractions overflows the stack of XLA's
+        TPU compiler (v5e, jax 0.9)."""
+        return tuple(_frozen_logits(frozen[i], v) for i, v in enumerate(vecs))
+
     def embed_rows(self, i: int, vecs: jax.Array, scalars: jax.Array) -> jax.Array:
         es = jax.vmap(lambda s: value_encode(s, self.edges).reshape(-1))(scalars)
         ev = self._evec(self.params, i, vecs)
@@ -169,11 +191,10 @@ class DataEncoder:
             vecs = jnp.asarray(np.asarray(table.vectors[i])[sub])
             fp = params["frozen"][i]
             st = adamw_init(fp, opt_cfg)
-            grad_fn = jax.jit(jax.value_and_grad(frozen_loss))
+            train = adamw_step(frozen_loss, opt_cfg)
             for step in range(cfg.frozen_steps):
                 bidx = rng.integers(0, vecs.shape[0], cfg.batch)
-                l, g = grad_fn(fp, vecs[bidx], labels[bidx])
-                fp, st = adamw_update(g, st, fp, opt_cfg)
+                fp, st, l = train(fp, st, vecs[bidx], labels[bidx])
             params["frozen"][i] = fp
             metrics[f"frozen_loss_col{i}"] = float(l)
 
@@ -183,24 +204,15 @@ class DataEncoder:
         )
         vec_subs = [jnp.asarray(np.asarray(table.vectors[i])[sub]) for i in range(len(self.vec_dims))]
 
-        def ae_loss(train_params, batch_idx):
-            p = {**params, "trainable": train_params["trainable"],
-                 "ae_enc": train_params["ae_enc"], "ae_dec": train_params["ae_dec"]}
-            loss = 0.0
-            for i in range(len(self.vec_dims)):
-                ev = self._evec(p, i, vec_subs[i][batch_idx])
-                e = jnp.concatenate([ev, es_all[batch_idx]], axis=-1)
-                rec = self._ae(p, e)
-                loss = loss + jnp.mean(jnp.square(rec - e))
-            return loss / len(self.vec_dims)
-
         tp = {"trainable": params["trainable"], "ae_enc": params["ae_enc"], "ae_dec": params["ae_dec"]}
         st = adamw_init(tp, opt_cfg)
-        grad_fn = jax.jit(jax.value_and_grad(ae_loss))
+        train = adamw_step(self._ae_loss, opt_cfg)
+        logits = jax.jit(self._frozen_batch_logits)
         for step in range(cfg.ae_steps):
             bidx = jnp.asarray(rng.integers(0, len(sub), cfg.batch))
-            l, g = grad_fn(tp, bidx)
-            tp, st = adamw_update(g, st, tp, opt_cfg)
+            vb = tuple(v[bidx] for v in vec_subs)
+            tp, st, l = train(tp, st, logits(params["frozen"], vb), vb,
+                              es_all[bidx])
         params.update(tp)
         metrics["ae_loss"] = float(l)
         self.params = params
@@ -214,28 +226,18 @@ class DataEncoder:
         es_new = jax.vmap(lambda s: value_encode(s, self.edges).reshape(-1))(scal_new)
         vec_new = [jnp.asarray(np.asarray(table.vectors[i])[new_rows]) for i in range(len(self.vec_dims))]
         params = self.params
-
-        def ae_loss(train_params, batch_idx):
-            p = {**params, "trainable": train_params["trainable"],
-                 "ae_enc": train_params["ae_enc"], "ae_dec": train_params["ae_dec"]}
-            loss = 0.0
-            for i in range(len(self.vec_dims)):
-                ev = self._evec(p, i, vec_new[i][batch_idx])
-                e = jnp.concatenate([ev, es_new[batch_idx]], axis=-1)
-                rec = self._ae(p, e)
-                loss = loss + jnp.mean(jnp.square(rec - e))
-            return loss / len(self.vec_dims)
-
         tp = {"trainable": params["trainable"], "ae_enc": params["ae_enc"], "ae_dec": params["ae_dec"]}
         opt_cfg = AdamWConfig(lr=cfg.lr * 0.5, weight_decay=1e-4)
         st = adamw_init(tp, opt_cfg)
-        grad_fn = jax.jit(jax.value_and_grad(ae_loss))
+        train = adamw_step(self._ae_loss, opt_cfg)
+        logits = jax.jit(self._frozen_batch_logits)
         nb = scal_new.shape[0]
         l = jnp.zeros(())
         for step in range(cfg.update_steps):
             bidx = jnp.asarray(rng.integers(0, nb, min(cfg.batch, nb)))
-            l, g = grad_fn(tp, bidx)
-            tp, st = adamw_update(g, st, tp, opt_cfg)
+            vb = tuple(v[bidx] for v in vec_new)
+            tp, st, l = train(tp, st, logits(params["frozen"], vb), vb,
+                              es_new[bidx])
         self.params = {**params, **tp}
         return {"ae_update_loss": float(l)}
 

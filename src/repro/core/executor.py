@@ -150,37 +150,40 @@ def rrf_extras(lists, *, kis, n_extra, rrf_k=RRF_K):
     widths. Returns (B, n_extra) ids, -1 padded, best-fused first.
 
     Cross-column dedup sums every occurrence's contribution: sort slots by
-    row id, segmented cumulative sums (cum/cumi are nondecreasing along the
-    row, so a running max of each segment-start value carries every slot
-    its own segment base), then read each run at its last slot."""
+    row id and segment-sum each run of equal ids. Contributions are
+    integers (2^30 // (rrf_k + 1 + rank)), so the sums are exact and
+    independent of summation order: rows with equal contributions tie
+    exactly, and ``top_k`` over the id-sorted runs breaks the tie by row id
+    on every backend."""
     sc_parts, inc_parts = [], []
     for lst, ki in zip(lists, kis):
         valid = lst >= 0
-        contrib = 1.0 / (rrf_k + 1.0
-                         + jnp.arange(lst.shape[1], dtype=jnp.float32))
-        sc_parts.append(jnp.where(valid, contrib[None, :], 0.0))
+        contrib = jnp.int32(1 << 30) // (
+            rrf_k + 1 + jnp.arange(lst.shape[1], dtype=jnp.int32))
+        sc_parts.append(jnp.where(valid, contrib[None, :], 0))
         inc_parts.append(valid & (jnp.arange(lst.shape[1]) < ki)[None, :])
     rows = jnp.concatenate(list(lists), axis=1)
     sc = jnp.concatenate(sc_parts, axis=1)
     inc = jnp.concatenate(inc_parts, axis=1).astype(jnp.int32)
     order = jnp.argsort(rows, axis=1)
     rs = jnp.take_along_axis(rows, order, axis=1)
-    cs = jnp.take_along_axis(sc, order, axis=1)
-    ins = jnp.take_along_axis(inc, order, axis=1)
-    cum = jnp.cumsum(cs, axis=1)
-    cumi = jnp.cumsum(ins, axis=1)
-    b = rs.shape[0]
-    seg_start = jnp.concatenate(
-        [jnp.ones((b, 1), bool), rs[:, 1:] != rs[:, :-1]], axis=1)
-    is_last = jnp.concatenate(
-        [rs[:, 1:] != rs[:, :-1], jnp.ones((b, 1), bool)], axis=1)
-    base = jax.lax.cummax(jnp.where(seg_start, cum - cs, -1.0), axis=1)
-    basei = jax.lax.cummax(jnp.where(seg_start, cumi - ins, -1), axis=1)
-    fused = jnp.where(is_last & (rs >= 0) & (cumi - basei == 0),
-                      cum - base, -1.0)
+    b, w = rs.shape
+    run = jnp.cumsum(jnp.concatenate(
+        [jnp.zeros((b, 1), jnp.int32),
+         (rs[:, 1:] != rs[:, :-1]).astype(jnp.int32)], axis=1), axis=1)
+
+    def per_run(op, x):
+        return jax.vmap(lambda v, r: op(v, r, num_segments=w))(
+            jnp.take_along_axis(x, order, axis=1), run)
+
+    fused = per_run(jax.ops.segment_sum, sc)  # (B, runs), id-sorted
+    n_inc = per_run(jax.ops.segment_sum, inc)
+    run_row = per_run(jax.ops.segment_max, rows)
+    fused = jnp.where((run_row >= 0) & (n_inc == 0), fused, -1)
     ne = min(n_extra, fused.shape[1])
     top_s, top_j = jax.lax.top_k(fused, ne)
-    out = jnp.where(top_s > 0.0, jnp.take_along_axis(rs, top_j, axis=1), -1)
+    out = jnp.where(top_s > 0, jnp.take_along_axis(run_row, top_j, axis=1),
+                    -1)
     if ne < n_extra:
         out = jnp.pad(out, ((0, 0), (0, n_extra - ne)), constant_values=-1)
     return out.astype(jnp.int32)

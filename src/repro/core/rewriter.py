@@ -25,7 +25,7 @@ from repro.core.query import (
     BEAM_GRID, ExecutionPlan, HOP_GRID, KMULT_GRID, MAX_SCAN_GRID, MHQ,
     NPROBE_GRID, PRECISION_GRID, STRATEGIES, SubqueryParams,
 )
-from repro.train.optimizer import AdamWConfig, adamw_init, adamw_update
+from repro.train.optimizer import AdamWConfig, adamw_init, adamw_step
 
 N_NP, N_MS, N_KM = len(NPROBE_GRID), len(MAX_SCAN_GRID), len(KMULT_GRID)
 N_BEAM, N_HOP = len(BEAM_GRID), len(HOP_GRID)
@@ -191,13 +191,12 @@ class MHQRewriter:
 
         opt_cfg = AdamWConfig(lr=cfg.lr, weight_decay=1e-4, grad_clip_norm=1.0)
         st = adamw_init(self.params, opt_cfg)
-        grad = jax.jit(jax.value_and_grad(loss_fn))
+        train = adamw_step(loss_fn, opt_cfg)
         rng = np.random.default_rng(cfg.seed)
         l = jnp.zeros(())
         for step in range(cfg.steps):
             idx = jnp.asarray(rng.integers(0, n, min(cfg.batch, n)))
-            l, g = grad(self.params, idx)
-            self.params, st = adamw_update(g, st, self.params, opt_cfg)
+            self.params, st, l = train(self.params, st, idx)
         # training accuracy
         strat, _, _, _ = self._heads(self.params, Xj)
         acc = float(jnp.mean(jnp.argmax(strat, -1) == y_strat))

@@ -14,8 +14,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.common import compat
-
 
 def bubble(n_stages: int, n_microbatches: int) -> float:
     return (n_stages - 1) / (n_microbatches + n_stages - 1)
@@ -64,7 +62,7 @@ def pipeline_apply(mesh: Mesh, axis: str, stage_fn, n_microbatches: int):
             jnp.where(stage == n_stages - 1, outs, jnp.zeros_like(outs)), axis)
         return outs
 
-    shard = compat.shard_map(
+    shard = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),

@@ -8,26 +8,27 @@ though the learned plans only ever look at ``max_scan`` candidates per
 query. This kernel closes that gap. Given a ``(B, S)`` candidate-row matrix
 (padded with -1), each grid step (query b, candidate block j):
 
-  * gathers the block's candidate rows — vectors of every weighted column
-    plus the scalar row — into VMEM scratch tiles via per-row HBM→VMEM
-    async copies (the table refs stay in ``pl.ANY``/HBM, so table size is
+  * copies the block's candidate ids into SMEM, then gathers each
+    candidate's row of the table's ``(n, 1, W)`` row view (every weighted
+    column plus the scalars, ``gather_view``) into VMEM scratch with one
+    HBM→VMEM async copy per row — the table stays in HBM, so its size is
     bounded by HBM, not the ~16 MB VMEM; the rows are arbitrary, so there
-    is no contiguous BlockSpec for them);
-  * scores the tile with one MXU dot per column and combines with the
-    query's column weights (l2 keeps the -||v||² and -||q||² terms so score
-    VALUES match ``table.similarity``, not just the ranking);
+    is no contiguous BlockSpec for them;
+  * scores each column with f32 VPU products and lane reductions (no
+    reduced-precision MXU pass) and combines with the query's column
+    weights (l2 keeps the -||v||² and -||q||² terms so score VALUES match
+    ``table.similarity``, not just the ranking);
   * evaluates the DNF predicate on the gathered scalars (OR over valid
     clauses of AND over active columns) and masks;
   * selects the block-local top-k by k rounds of max+knockout, where the
     knockout removes every slot carrying the winning ROW ID — duplicate
     candidates (the rerank union) can never crowd distinct rows out of a
-    block's k slots.
+    block's k slots — into one lane-dense output row.
 
-Per-block candidates merge in the caller (``merge_topk_unique``): one
-dedup-by-id pass plus a (-score, id) lexsort, so ties break by smaller row
-id — the same rule the pure-jnp reference (``ref.gather_score_ref``) and the
-NumPy test oracle use, which keeps kernel-vs-reference id parity exact on
-tie-free data.
+Per-block candidates merge in the caller (``merge_topk_unique``) by the same
+max + knockout-by-id rounds, so ties break by smaller row id — the rule the
+pure-jnp reference (``ref.gather_score_ref``) and the NumPy test oracle use,
+which keeps kernel-vs-reference id parity exact on tie-free data.
 
 Off-TPU the public entry ``gather_score_topk`` runs the reference path by
 default (the interpreter would execute the Pallas kernel in Python, row by
@@ -44,7 +45,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.shapes import GATHER_BLOCK_S, ID_SENTINEL, NEG
+from repro.kernels.shapes import GATHER_BLOCK_S, ID_SENTINEL, NEG, round128
 
 
 def _pred_fields(pred):
@@ -62,115 +63,220 @@ def merge_topk_unique(ids, scores, k: int):
     suppressed and ties broken by smaller row id.
 
     Padded slots carry id -1 / score NEG. Duplicate ids score identically
-    (same row, same per-row dot), so keeping the first occurrence is exact.
-    """
+    (same row, same per-row dot). k rounds of max + knockout-by-id: each
+    round is one pass over the pool, with no sort — XLA's TPU sort of a
+    pool tens of thousands wide takes tens of seconds to compile, and a
+    serving path compiles one such merge per plan shape."""
+    ids = ids.astype(jnp.int32)
+    lane = jnp.arange(k, dtype=jnp.int32)[None, :]
 
-    def one(cid, s):
-        order = jnp.argsort(cid)
-        sc = cid[order]
-        first = jnp.concatenate(
-            [jnp.ones((1,), bool), sc[1:] != sc[:-1]])
-        keep = jnp.zeros_like(first).at[order].set(first) & (cid >= 0)
-        s2 = jnp.where(keep, s, NEG)
-        key = jnp.where(cid >= 0, cid, ID_SENTINEL)
-        sel = jnp.lexsort((key, -s2))[:k]
-        top = s2[sel]
-        out_ids = jnp.where(top > NEG / 2, cid[sel], -1)
-        return out_ids.astype(jnp.int32), top
+    def select(r, carry):
+        s, out_i, out_s = carry
+        m = jnp.max(s, axis=1, keepdims=True)
+        first = jnp.min(jnp.where((s >= m) & (s > NEG / 2), ids,
+                                  jnp.int32(ID_SENTINEL)),
+                        axis=1, keepdims=True)
+        hit = m > NEG / 2
+        out_i = jnp.where(lane == r, jnp.where(hit, first, -1), out_i)
+        out_s = jnp.where(lane == r, jnp.where(hit, m, NEG), out_s)
+        return jnp.where(ids == first, NEG, s), out_i, out_s
 
-    return jax.vmap(one)(ids, scores)
+    b = ids.shape[0]
+    _, out_i, out_s = jax.lax.fori_loop(
+        0, k, select,
+        (jnp.where(ids >= 0, scores.astype(jnp.float32), NEG),
+         jnp.full((b, k), -1, jnp.int32), jnp.full((b, k), NEG, jnp.float32)))
+    return out_i, out_s
 
 
 # ---------------------------------------------------------------------------
 # Pallas kernel
 # ---------------------------------------------------------------------------
 
-def _kernel(cand_ref, scal_ref, w_ref, lo_ref, hi_ref, act_ref, cval_ref,
-            *refs, k: int, block_s: int, n_vec: int, metric: str,
-            apply_pred: bool, int8: bool = False):
-    vec_refs = refs[:n_vec]  # pl.ANY (HBM) — full table columns
-    pos = n_vec
+def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
+            view_hbm, out_s_ref, out_i_ref, out_q_ref, ids_smem, cid_tile,
+            tile_ref, sem, *, k: int, k_pad: int, block_s: int, nb: int,
+            segs: tuple, n_clauses: int, metric: str, apply_pred: bool,
+            int8: bool):
+    """One (query b, candidate block j) grid step. Every per-candidate
+    quantity is a (block_s, 1) column; each output is one lane-dense
+    (1, k_pad) row, so no scalar is ever stored into a vector ref."""
+    # the block's candidate ids land in SMEM, so every row copy below
+    # takes a scalar address
+    blk = pl.program_id(0) * nb + pl.program_id(1)
+    ids_copy = pltpu.make_async_copy(cand_hbm.at[blk], ids_smem, sem.at[0])
+    ids_copy.start()
+    ids_copy.wait()
+
+    def row_copy(t, r):
+        return pltpu.make_async_copy(view_hbm.at[pl.ds(r, 1)],
+                                     tile_ref.at[pl.ds(t, 1)], sem.at[1])
+
+    # arbitrary-row gather: one HBM->VMEM copy per valid candidate row (all
+    # of its columns and scalars in one view row), all started before any
+    # is waited on. The table never enters VMEM, so its size is bounded by
+    # HBM; padding slots (-1) move nothing.
+    def start(t, _):
+        r = ids_smem[0, t]
+
+        @pl.when(r >= 0)
+        def _():
+            row_copy(t, r).start()
+        return 0
+
+    def wait(t, _):
+        r = ids_smem[0, t]
+
+        @pl.when(r >= 0)
+        def _():
+            row_copy(t, r).wait()
+        cid_tile[pl.ds(t, 1), :] = jnp.full((1, 128), r, jnp.int32)
+        return 0
+
+    jax.lax.fori_loop(0, block_s, start, 0)
+    jax.lax.fori_loop(0, block_s, wait, 0)
+
+    cid = cid_tile[:, 0:1]  # (BS, 1) i32, -1 = padding
+    valid = cid >= 0
+    tile = tile_ref[...]
+    tile = tile.reshape(block_s, tile.shape[-1])
+    meta = tile[:, -128:]  # [scalars | int8 scales | 0-pad], 128 lanes
     if int8:
-        scale_refs = refs[pos:pos + n_vec]  # pl.ANY (HBM) — (n, 1) f32
-        pos += n_vec
-    q_refs = refs[pos:pos + n_vec]
-    out_s_ref, out_i_ref, out_q_ref = refs[pos + n_vec: pos + n_vec + 3]
-    scratch = refs[pos + n_vec + 3:]
-    vec_tiles = scratch[:n_vec]  # VMEM (BS, d_i) per column (f32 or int8)
-    pos = n_vec
-    if int8:
-        scale_tiles = scratch[pos:pos + n_vec]  # VMEM (BS, 1) f32
-        pos += n_vec
-    scal_tile = scratch[pos]  # VMEM (BS, M)
-    sem = scratch[pos + 1]  # DMA completion semaphore
-
-    cid = cand_ref[...].reshape(block_s, 1)  # (BS, 1) i32, -1 = padding
-    n = scal_ref.shape[0]
-    idc = jnp.clip(cid[:, 0], 0, n - 1)  # clamp padding for safe gathers
-
-    def gather(src_ref, tile_ref):
-        # arbitrary-row gather: one HBM→VMEM async copy per candidate row
-        # into the block's scratch tile — the table itself never enters
-        # VMEM, so table size is bounded by HBM
-        def body(t, _):
-            dma = pltpu.make_async_copy(src_ref.at[pl.ds(idc[t], 1), :],
-                                        tile_ref.at[pl.ds(t, 1), :], sem)
-            dma.start()
-            dma.wait()
-            return 0
-
-        jax.lax.fori_loop(0, block_s, body, 0)
-
+        meta = jax.lax.bitcast_convert_type(meta, jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    w = w_ref[0]  # (1, n_vec)
     total = jnp.zeros((block_s, 1), jnp.float32)
-    for i in range(n_vec):
-        gather(vec_refs[i], vec_tiles[i])
-        q = q_refs[i][...]  # (1, d)
+    off = 0
+    for i, (width, scale_lane) in enumerate(segs):
+        seg = tile[:, off:off + width]
+        q = q_ref[0, :, off:off + width]  # (1, P) f32 or (4, P) for int8
+        off += width
+        # VPU multiplies + lane reductions: exact f32 products (no
+        # reduced-precision MXU passes), so score VALUES match
+        # ``table.similarity``; zero lane padding adds nothing
         if int8:
-            # quantized tier: the gathered tile is int8 (4× fewer HBM
-            # bytes per row) — one dot on the cast tile, then the per-row
-            # absmax dequant scale (score(v·s) = s·score(v); l2 norms
-            # rescale by s²)
-            gather(scale_refs[i], scale_tiles[i])
-            tile = vec_tiles[i][...].astype(jnp.float32)  # (BS, d)
-            sc = scale_tiles[i][...]  # (BS, 1)
-            s = jnp.dot(tile, q.T,
-                        preferred_element_type=jnp.float32) * sc
-            if metric == "l2":
-                s = (2.0 * s
-                     - jnp.sum(tile * tile, axis=1, keepdims=True) * sc * sc
-                     - jnp.sum(q * q))
+            # quantized tier: the segment holds d/4 words of 4 packed int8
+            # lanes (byte p of word m is element 4m+p; q is regrouped the
+            # same way) — a quarter of the fp32 bytes per gathered row
+            s = jnp.zeros((block_s, 1), jnp.float32)
+            nn = jnp.zeros((block_s, 1), jnp.float32)
+            for p in range(4):
+                e = ((seg << (24 - 8 * p)) >> 24).astype(jnp.float32)
+                s = s + jnp.sum(e * q[p:p + 1], axis=1, keepdims=True)
+                nn = nn + jnp.sum(e * e, axis=1, keepdims=True)
+            # score(v·s) = s·score(v); l2 norms rescale by s²
+            sc = jnp.sum(jnp.where(lane == scale_lane, meta, 0.0), axis=1,
+                         keepdims=True)
+            s = s * sc
+            nn = nn * sc * sc
         else:
-            tile = vec_tiles[i][...]  # (BS, d)
-            s = jnp.dot(tile, q.T,
-                        preferred_element_type=jnp.float32)  # (BS, 1)
-            if metric == "l2":
-                s = (2.0 * s - jnp.sum(tile * tile, axis=1, keepdims=True)
-                     - jnp.sum(q * q))
-        total = total + w_ref[0, i] * s
+            s = jnp.sum(seg * q, axis=1, keepdims=True)
+            nn = jnp.sum(seg * seg, axis=1, keepdims=True)
+        if metric == "l2":
+            s = 2.0 * s - nn - jnp.sum(q * q)
+        total = total + w[:, i:i + 1] * jnp.where(valid, s, 0.0)
 
+    ok = valid
     if apply_pred:
-        gather(scal_ref, scal_tile)
-        st = scal_tile[...]  # (BS, M)
-        lo, hi, act = lo_ref[...][0], hi_ref[...][0], act_ref[...][0]  # (C, M)
-        ok_cm = ((st[:, None, :] >= lo) & (st[:, None, :] <= hi)) \
-            | (act < 0.5)  # (BS, C, M)
-        clause = jnp.all(ok_cm, axis=-1) & (cval_ref[...][0] > 0.5)  # (BS, C)
-        ok = jnp.any(clause, axis=-1)[:, None]
-    else:
-        ok = jnp.ones((block_s, 1), bool)
-    qual = ok & (cid >= 0)
-    out_q_ref[0, 0] = jnp.sum(qual.astype(jnp.int32))
+        # lo/hi/active are padded to the meta segment's 128 lanes with
+        # inactive columns, so lanes past the scalars always pass
+        any_clause = jnp.zeros((block_s, 1), bool)
+        for c in range(n_clauses):
+            sat = (((meta >= lo_ref[0, c:c + 1, :])
+                    & (meta <= hi_ref[0, c:c + 1, :]))
+                   | (act_ref[0, c:c + 1, :] < 0.5))  # (BS, 128)
+            clause = jnp.min(sat.astype(jnp.int32), axis=1, keepdims=True)
+            any_clause = any_clause | (
+                (clause > 0) & (cval_ref[0, :, c:c + 1] > 0.5))
+        ok = ok & any_clause
+    out_q_ref[0] = jnp.broadcast_to(
+        jnp.sum(ok.astype(jnp.int32), axis=0, keepdims=True), (1, 128))
 
-    s = jnp.where(qual, total, NEG)
-    for j in range(k):
-        m = jnp.max(s)
+    out_s_ref[0], out_i_ref[0] = block_topk(
+        jnp.where(ok, total, NEG), cid, k=k, k_pad=k_pad)
+
+
+def block_topk(s, ids, *, k: int, k_pad: int):
+    """Top-k of a (R, 1) score column by k rounds of max + knockout, where
+    the knockout removes every slot carrying the winning id (duplicates
+    never take two slots) and ties go to the smaller id. -> lane-dense
+    (1, k_pad) score and id rows; unfilled slots hold NEG / -1. Vector ops
+    only, so the rounds lower on Mosaic as well as in the interpreter."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, k_pad), 1)
+
+    def select(r, carry):
+        s, row_s, row_i = carry
+        m = jnp.max(s, axis=0, keepdims=True)  # (1, 1)
         is_max = (s >= m) & (s > NEG / 2)
-        first = jnp.min(jnp.where(is_max, cid, jnp.int32(ID_SENTINEL)))
-        out_s_ref[0, 0, j] = m
-        out_i_ref[0, 0, j] = jnp.where(m > NEG / 2, first, -1)
-        # knock out every slot carrying this ROW ID, not just one slot —
-        # duplicates must not occupy multiple of the block's k slots
-        s = jnp.where(cid == first, NEG, s)
+        first = jnp.min(jnp.where(is_max, ids, jnp.int32(ID_SENTINEL)),
+                        axis=0, keepdims=True)
+        row_s = jnp.where(lane == r, m, row_s)
+        row_i = jnp.where(lane == r, jnp.where(m > NEG / 2, first, -1),
+                          row_i)
+        return jnp.where(ids == first, NEG, s), row_s, row_i
+
+    _, row_s, row_i = jax.lax.fori_loop(
+        0, k, select, (s, jnp.full((1, k_pad), NEG, jnp.float32),
+                       jnp.full((1, k_pad), -1, jnp.int32)))
+    return row_s, row_i
+
+
+def gather_view(vectors, scalars, scales=None):
+    """The kernel's (n, 1, W) row view of a table: per vector column one
+    lane-aligned segment, then a 128-lane meta segment [scalars | per-column
+    int8 dequant scales | 0]. Mosaic copies rows only along a leading axis
+    and in whole 128-lane tiles, so one view row is one DMA that brings a
+    candidate's every column and scalar at once.
+
+    fp32: segments are the f32 rows zero-padded to 128 lanes. int8
+    (``scales`` given, ``vectors`` the int8 replicas): segments hold four
+    int8 lanes per i32 word (byte p of word m is element 4m+p), and the
+    whole view is i32 with the meta segment as f32 bits.
+    -> (view, segs) with segs = ((width, meta scale lane or -1), ...)."""
+    n, m = scalars.shape
+    n_vec = len(vectors)
+    assert m + n_vec <= 128, (m, n_vec)
+    parts, segs = [], []
+    for i, v in enumerate(vectors):
+        if scales is None:
+            width = round128(v.shape[1])
+            parts.append(jnp.pad(v.astype(jnp.float32),
+                                 ((0, 0), (0, width - v.shape[1]))))
+            segs.append((width, -1))
+        else:
+            d4 = -(-v.shape[1] // 4)
+            width = round128(d4)
+            b = jnp.pad(v, ((0, 0), (0, 4 * d4 - v.shape[1])))
+            b = (b.astype(jnp.int32) & 0xFF).reshape(n, d4, 4)
+            words = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) \
+                | (b[..., 3] << 24)
+            parts.append(jnp.pad(words, ((0, 0), (0, width - d4))))
+            segs.append((width, m + i))
+    meta = [scalars.astype(jnp.float32)]
+    if scales is not None:
+        meta += [s.astype(jnp.float32)[:, None] for s in scales]
+    meta = jnp.concatenate(meta, axis=1)
+    meta = jnp.pad(meta, ((0, 0), (0, 128 - meta.shape[1])))
+    if scales is not None:
+        meta = jax.lax.bitcast_convert_type(meta, jnp.int32)
+    return jnp.concatenate(parts + [meta], axis=1)[:, None, :], tuple(segs)
+
+
+def _query_view(qs, segs, int8: bool):
+    """(B, R, sum of segment widths) queries laid out like the view's
+    vector segments: R = 1 row of zero-padded f32 lanes, or for int8 R = 4
+    rows where row p holds the elements 4m+p the packed byte p multiplies."""
+    parts = []
+    for q, (width, _) in zip(qs, segs):
+        q = q.astype(jnp.float32)
+        b, d = q.shape
+        if int8:
+            q = jnp.pad(q, ((0, 0), (0, (-d) % 4)))
+            q = jnp.swapaxes(q.reshape(b, -1, 4), 1, 2)  # (B, 4, d/4)
+        else:
+            q = q[:, None, :]
+        parts.append(jnp.pad(q, ((0, 0), (0, 0), (0, width - q.shape[2]))))
+    return jnp.concatenate(parts, axis=2)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_s", "metric",
@@ -184,66 +290,71 @@ def gather_score_blocks(cand, vectors, qs, weights, scalars, lo, hi, active,
     ``cand`` (B, S) i32 candidate rows (-1 = padding), S a multiple of
     ``block_s``; block ids are ROW ids (block-locally deduplicated).
 
-    With ``scales`` (tuple of (n, 1) f32 per-row dequant scales) the
-    ``vectors`` are the int8 replicas: tiles gather as int8 and dequantize
-    per row in VMEM — the quantized scoring tier."""
+    With ``scales`` (tuple of (n,) f32 per-row dequant scales) the
+    ``vectors`` are the int8 replicas: rows gather as packed int8 words and
+    dequantize per row in VMEM — the quantized scoring tier.
+
+    Layout for Mosaic: the table enters as one (n, 1, W) row view
+    (``gather_view``, built inside the caller's jit), candidate ids as
+    lane-padded (B·nb, 1, block_s) rows copied into SMEM, per-query operands as
+    (B, R, X) arrays whose blocks span their trailing dims, and each output
+    block is a lane-dense (1, k_pad) row (k_pad = k rounded up to 128),
+    sliced back to k here."""
     b, s_tot = cand.shape
     assert s_tot % block_s == 0, (s_tot, block_s)
     nb = s_tot // block_s
     n, m = scalars.shape
-    n_vec = len(vectors)
-    c = lo.shape[1]
     int8 = scales is not None
-    kern = functools.partial(_kernel, k=k, block_s=block_s, n_vec=n_vec,
-                             metric=metric, apply_pred=apply_pred, int8=int8)
-    in_specs = [
-        pl.BlockSpec((1, block_s), lambda b_, j: (b_, j)),  # candidates
-        pl.BlockSpec(memory_space=pl.ANY),  # scalars — stay in HBM
-        pl.BlockSpec((1, n_vec), lambda b_, j: (b_, 0)),  # weights
-        pl.BlockSpec((1, c, m), lambda b_, j: (b_, 0, 0)),  # lo
-        pl.BlockSpec((1, c, m), lambda b_, j: (b_, 0, 0)),  # hi
-        pl.BlockSpec((1, c, m), lambda b_, j: (b_, 0, 0)),  # active
-        pl.BlockSpec((1, c), lambda b_, j: (b_, 0)),  # clause_valid
-    ]
-    for _ in vectors:
-        in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # columns — HBM
-    if int8:
-        for _ in vectors:
-            in_specs.append(pl.BlockSpec(memory_space=pl.ANY))  # scales
-    for v in vectors:
-        in_specs.append(
-            pl.BlockSpec((1, v.shape[1]), lambda b_, j: (b_, 0)))
-    tile_dtype = jnp.int8 if int8 else jnp.float32
-    scratch_shapes = [pltpu.VMEM((block_s, v.shape[1]), tile_dtype)
-                      for v in vectors]
-    if int8:
-        scratch_shapes += [pltpu.VMEM((block_s, 1), jnp.float32)
-                           for _ in vectors]
-    scratch_shapes += [pltpu.VMEM((block_s, m), jnp.float32),
-                       pltpu.SemaphoreType.DMA(())]
-    operands = [cand, scalars, weights, lo, hi, active, clause_valid,
-                *[v for v in vectors]]
-    if int8:
-        operands += [s for s in scales]
-    operands += [q for q in qs]
+    view, segs = gather_view(vectors, scalars, scales)
+    q3 = _query_view(qs, segs, int8)
+    k_pad = round128(k)
+    kern = functools.partial(
+        _kernel, k=k, k_pad=k_pad, block_s=block_s, nb=nb, segs=segs,
+        n_clauses=lo.shape[1], metric=metric, apply_pred=apply_pred,
+        int8=int8)
+    # predicate fields padded to the meta segment: extra lanes inactive
+    pad = ((0, 0), (0, 0), (0, 128 - m))
+    lo3 = jnp.pad(lo.astype(jnp.float32), pad)
+    hi3 = jnp.pad(hi.astype(jnp.float32), pad)
+    act3 = jnp.pad(active.astype(jnp.float32), pad)
+    w3 = weights.astype(jnp.float32)[:, None, :]
+    # one id row per block, lane-padded: ids copy in whole 128-lane tiles
+    ids3 = jnp.pad(cand.astype(jnp.int32).reshape(b * nb, 1, block_s),
+                   ((0, 0), (0, 0), (0, round128(block_s) - block_s)),
+                   constant_values=-1)
+    cval3 = clause_valid.astype(jnp.float32)[:, None, :]
+
+    def per_query(x):
+        return pl.BlockSpec((1,) + x.shape[1:],
+                            lambda b_, j: (b_,) + (0,) * (x.ndim - 1))
+
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+
+    def out_block(width):
+        return pl.BlockSpec((1, 1, width), lambda b_, j: (b_ * nb + j, 0, 0))
+
     out_s, out_i, out_q = pl.pallas_call(
         kern,
         grid=(b, nb),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, k), lambda b_, j: (b_, j, 0)),
-            pl.BlockSpec((1, 1, k), lambda b_, j: (b_, j, 0)),
-            pl.BlockSpec((1, 1), lambda b_, j: (b_, j)),
-        ],
+        in_specs=[hbm, per_query(w3), per_query(lo3), per_query(hi3),
+                  per_query(act3), per_query(cval3), per_query(q3), hbm],
+        out_specs=[out_block(k_pad), out_block(k_pad), out_block(128)],
         out_shape=[
-            jax.ShapeDtypeStruct((b, nb, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, nb, k), jnp.int32),
-            jax.ShapeDtypeStruct((b, nb), jnp.int32),
+            jax.ShapeDtypeStruct((b * nb, 1, k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((b * nb, 1, k_pad), jnp.int32),
+            jax.ShapeDtypeStruct((b * nb, 1, 128), jnp.int32),
         ],
-        scratch_shapes=scratch_shapes,
+        scratch_shapes=[
+            pltpu.SMEM((1, ids3.shape[-1]), jnp.int32),  # block's cand ids
+            pltpu.VMEM((block_s, 128), jnp.int32),  # the same ids as a column
+            pltpu.VMEM((block_s, 1, view.shape[-1]), view.dtype),  # rows
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
         interpret=interpret,
-    )(*operands)
-    return out_s, out_i, out_q
+    )(ids3, w3, lo3, hi3, act3, cval3, q3, view)
+    return (out_s[:, 0, :k].reshape(b, nb, k),
+            out_i[:, 0, :k].reshape(b, nb, k),
+            out_q[:, 0, 0].reshape(b, nb))
 
 
 # ---------------------------------------------------------------------------
@@ -305,7 +416,7 @@ def gather_score_topk(cand, vectors, qs, weights, scalars, pred=None, *,
     if pad:
         cand = jnp.pad(cand, ((0, 0), (0, pad)), constant_values=-1)
     scales2 = None if scales is None else tuple(
-        s.reshape(-1, 1).astype(jnp.float32) for s in scales)
+        s.astype(jnp.float32) for s in scales)
     out_s, out_i, out_q = gather_score_blocks(
         cand, tuple(vectors), tuple(qs), weights, scalars, lo, hi, act, cval,
         scales2, k=k, block_s=bs, metric=metric, apply_pred=apply_pred,

@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.shapes import ID_SENTINEL, NEG, SCAN_BLOCK_ROWS
+from repro.kernels.gather_score import block_topk
+from repro.kernels.shapes import NEG, SCAN_BLOCK_ROWS, round128
 
 
 def quantize_rows(vectors: jax.Array):
@@ -39,13 +40,8 @@ def _kernel(q_ref, vec_ref, scale_ref, scal_ref, lo_ref, hi_ref, act_ref,
     gid = i * block_rows + row
     valid = gid < nrows_ref[0, 0]
     s = jnp.where(ok & valid, scores, NEG)
-    for j in range(k):
-        m = jnp.max(s)
-        is_max = (s >= m) & (s > NEG / 2)
-        first = jnp.min(jnp.where(is_max, gid, jnp.int32(ID_SENTINEL)))
-        out_s_ref[0, j] = m
-        out_i_ref[0, j] = jnp.where(m > NEG / 2, first, -1)
-        s = jnp.where(gid == first, NEG, s)
+    out_s_ref[0], out_i_ref[0] = block_topk(s, gid, k=k,
+                                            k_pad=out_s_ref.shape[-1])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "block_rows", "interpret"))
@@ -56,6 +52,7 @@ def int8_topk_blocks(q, vec_i8, scales, scalars, lo, hi, active, n_rows, *,
     m = scalars.shape[1]
     assert n % block_rows == 0
     nb = n // block_rows
+    k_pad = round128(k)  # lane-dense (1, k_pad) output rows
     kern = functools.partial(_kernel, k=k, block_rows=block_rows)
     out_s, out_i = pl.pallas_call(
         kern,
@@ -71,15 +68,15 @@ def int8_topk_blocks(q, vec_i8, scales, scalars, lo, hi, active, n_rows, *,
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
-            pl.BlockSpec((1, k), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, k_pad), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, k_pad), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((nb, k), jnp.float32),
-            jax.ShapeDtypeStruct((nb, k), jnp.int32),
+            jax.ShapeDtypeStruct((nb, 1, k_pad), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, k_pad), jnp.int32),
         ],
         interpret=interpret,
     )(q[None, :], vec_i8, scales[:, None], scalars, lo[None, :], hi[None, :],
       active[None, :].astype(jnp.float32),
       jnp.asarray(n_rows, jnp.int32).reshape(1, 1))
-    return out_s, out_i
+    return out_s[:, 0, :k], out_i[:, 0, :k]

@@ -79,17 +79,24 @@ def int8_scan_tile_bytes(dim: int, n_scalars: int, *, k: int = MAX_TOPK,
     return tile + pinned + out
 
 
+def round128(x: int) -> int:
+    """``x`` rounded up to whole 128-lane vregs."""
+    return -(-x // 128) * 128
+
+
 def gather_tile_bytes(dims: tuple, n_scalars: int, n_clauses: int, *,
                       k: int = MAX_TOPK,
                       block_s: int = GATHER_BLOCK_S) -> int:
-    """Resident bytes per grid step of ``gather_score_blocks``: one
-    (block_s, d_i) f32 VMEM scratch per vector column + the gathered
-    (block_s, n_scalars) scalar tile + pinned per-query operands."""
-    scratch = block_s * sum(dims) * _F32
-    scal = block_s * n_scalars * _F32
-    pinned = (sum(dims) + n_clauses * (2 * n_scalars + 1) + block_s) * _F32
-    out = 2 * k * _F32
-    return scratch + scal + pinned + out
+    """Resident bytes per grid step of ``gather_score_blocks``: the
+    (block_s, 1, W) scratch of gathered row-view rows (each column padded
+    to 128 lanes, then a 128-lane scalar segment), the (block_s, 128) id
+    column + pinned per-query operands and output rows. The scalars ride
+    in the meta segment, so ``n_scalars`` adds nothing while it fits."""
+    w = sum(round128(d) for d in dims) + 128
+    scratch = block_s * (w + 128) * _F32
+    pinned = (w + 3 * n_clauses * 128 + n_clauses) * _F32
+    out = 2 * round128(k) * _F32
+    return scratch + pinned + out
 
 
 def beam_tile_bytes(dim: int, n_scalars: int, n_clauses: int = 4, *,
@@ -123,12 +130,8 @@ def beam_tile_bytes(dim: int, n_scalars: int, n_clauses: int = 4, *,
 def int8_gather_tile_bytes(dims: tuple, n_scalars: int, n_clauses: int, *,
                            k: int = MAX_TOPK,
                            block_s: int = GATHER_BLOCK_S) -> int:
-    """``gather_tile_bytes`` for the quantized tier: each column's gathered
-    tile is int8 (1 B/elem) plus a (block_s, 1) f32 per-row dequant scale
-    tile; everything else (scalar tile, pinned query/predicate operands,
-    output pools) is unchanged."""
-    scratch = block_s * sum(d + _F32 for d in dims)  # int8 tile + scale col
-    scal = block_s * n_scalars * _F32
-    pinned = (sum(dims) + n_clauses * (2 * n_scalars + 1) + block_s) * _F32
-    out = 2 * k * _F32
-    return scratch + scal + pinned + out
+    """``gather_tile_bytes`` for the quantized tier: each column's segment
+    is d/4 i32 words (four packed int8 lanes) padded to 128 lanes; the meta
+    segment carries the scalars and the dequant scales."""
+    return gather_tile_bytes(tuple(-(-d // 4) for d in dims), n_scalars,
+                             n_clauses, k=k, block_s=block_s)

@@ -21,8 +21,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.common import compat
-
 from repro.common import nn
 from repro.configs.base import ModelConfig
 from repro.models.ffn import ffn_apply
@@ -103,7 +101,7 @@ def moe_apply_sharded(p, cfg: ModelConfig, x: jax.Array, *, batch_axes,
         drop_frac = 1.0 - jnp.mean(keep.astype(jnp.float32))
         return y.reshape(bl, s, d), probs, top_i, drop_frac
 
-    y, probs, top_i, drop = compat.shard_map(
+    y, probs, top_i, drop = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, None, None), P(), P(model_axis, bspec, None),
                   P(model_axis, bspec, None), P(model_axis, None, bspec)),

@@ -61,7 +61,7 @@ from repro.vectordb.distributed import (
     build_sharded_ivf, sharded_batch_topk, sharded_ivf_topk, sharded_topk_ref,
 )
 from repro.vectordb.predicates import eval_mask
-from repro.vectordb.table import Table
+from repro.vectordb.table import Table, exact_similarity
 
 # Dense-score budget: each chunk holds (batch, n_rows) f32 score matrices
 # per active vector column; chunks are sized so batch · n_rows stays under
@@ -324,11 +324,11 @@ def warm_bucket_ladder(execute_batch, queries: list, batch_size: int) -> None:
 @partial(jax.jit, static_argnames=("metric",))
 def _dense_scores(vectors, q_b, *, metric):
     """(B, n) similarities of every row against every query in the batch —
-    ONE multithreaded GEMM instead of B (max_scan, d) vector gathers. All
-    downstream kernels gather f32 scores, not d-dim vectors."""
-    from repro.vectordb.table import similarity
-
-    return jax.vmap(lambda q: similarity(q, vectors, metric))(q_b)
+    ONE fused pass over the table instead of B (max_scan, d) vector
+    gathers, in f32 products (``exact_similarity``): these scores are
+    returned as results. All downstream kernels gather f32 scores, not
+    d-dim vectors."""
+    return jax.vmap(lambda q: exact_similarity(q, vectors, metric))(q_b)
 
 
 def compute_batch_scores(table: Table, queries: list[MHQ]) -> tuple:
@@ -417,14 +417,12 @@ def _sharded_exact_retry(vectors, scalars, pred_b, q_b, w_b, need_b, *,
     well-filled shards contribute nothing — their probed top-k stands),
     then one top-k. Used when the escalated queries span most shards: one
     batched retry beats a per-shard dispatch loop."""
-    from repro.vectordb.table import similarity
-
     n = scalars.shape[0]
     s_count = need_b.shape[1]
     ws = jnp.zeros((w_b.shape[0], n), jnp.float32)
     for i, v in enumerate(vectors):
         ws = ws + w_b[:, i, None] * jax.vmap(
-            lambda q, vv=v: similarity(q, vv, metric))(q_b[i])
+            lambda q, vv=v: exact_similarity(q, vv, metric))(q_b[i])
     shard_of = jnp.minimum(jnp.arange(n, dtype=jnp.int32) // shard_len,
                            s_count - 1)
     allow = need_b[:, shard_of]
@@ -688,10 +686,13 @@ class BatchedHybridExecutor:
         is bit-for-bit the single-device candidate-local path."""
         if col not in self._sivf:
             base = self.indexes[col]
-            self._sivf[col] = build_sharded_ivf(
+            sivf = build_sharded_ivf(
                 self.table.vectors[col], self.n_shards,
                 n_clusters=base.n_clusters,
                 seed=col, metric=self.table.schema.metric, base_index=base)
+            if self.mesh is not None:
+                sivf = sivf.placed(self.mesh, self.shard_axes)
+            self._sivf[col] = sivf
         return self._sivf[col]
 
     def _sivf_fn(self, key, act: tuple):

@@ -185,11 +185,27 @@ class CompactionScheduler:
         self._pool = concurrent.futures.ThreadPoolExecutor(max_workers=1)
         self._inflight: Optional[concurrent.futures.Future] = None
         self.n_scheduled = 0
+        # the first compaction that raised; no compaction is scheduled after
+        # it, and drain() re-raises it so the failure reaches the caller
+        self.error: Optional[BaseException] = None
+
+    def _reap(self) -> bool:
+        """Collect a finished compaction. -> True when none is in flight."""
+        f = self._inflight
+        if f is None:
+            return True
+        if not f.done():
+            return False
+        self._inflight = None
+        if self.error is None and f.exception() is not None:
+            self.error = f.exception()
+        return True
 
     def maybe_schedule(self) -> bool:
-        """Submit one compaction if the hot segment needs it and none is
-        already in flight. Returns True when one was submitted."""
-        if self._inflight is not None and not self._inflight.done():
+        """Submit one compaction if the hot segment needs it, none is
+        already in flight and none has failed. Returns True when one was
+        submitted."""
+        if not self._reap() or self.error is not None:
             return False
         if not self.tiered.needs_compaction():
             return False
@@ -198,11 +214,14 @@ class CompactionScheduler:
         return True
 
     def drain(self) -> None:
-        """Wait out the in-flight compaction and stop the worker."""
+        """Wait out the in-flight compaction and stop the worker; re-raise
+        the first compaction failure."""
         if self._inflight is not None:
-            self._inflight.result()
-            self._inflight = None
+            concurrent.futures.wait([self._inflight])
+            self._reap()
         self._pool.shutdown(wait=True)
+        if self.error is not None:
+            raise self.error
 
 
 class AsyncServingEngine:
@@ -293,12 +312,15 @@ class AsyncServingEngine:
         self._pool.shutdown(wait=False, cancel_futures=True)
         self._pool = None
         if self._compactor is not None:
-            # let the in-flight compaction land (it owns published state)
-            await asyncio.get_running_loop().run_in_executor(
-                None, self._compactor.drain)
-            if getattr(self.bq, "_compactor", None) is self._compactor:
-                self.bq._compactor = None
-            self._compactor = None
+            # let the in-flight compaction land (it owns published state);
+            # a compaction that raised re-raises here, out of stop()
+            try:
+                await asyncio.get_running_loop().run_in_executor(
+                    None, self._compactor.drain)
+            finally:
+                if getattr(self.bq, "_compactor", None) is self._compactor:
+                    self.bq._compactor = None
+                self._compactor = None
 
     async def __aenter__(self) -> "AsyncServingEngine":
         return await self.start()

@@ -96,48 +96,74 @@ def adamw_init(params: PyTree, cfg: AdamWConfig) -> dict:
     }
 
 
+@partial(jax.jit, static_argnames=("cfg",))
+def _leaf_update(g, m, v, p, clip, lr, c1, c2, *, cfg: AdamWConfig):
+    """One parameter leaf's AdamW step — elementwise only, one small
+    program per leaf shape instead of an eager op each."""
+    v_dtype = "bfloat16" if cfg.state_dtype == "int8" else cfg.state_dtype
+    g32 = g.astype(jnp.float32) * clip
+    m32 = _read_moment(m, g.shape, cfg.state_dtype)
+    v32 = _read_moment(v, g.shape, v_dtype)
+    m32 = cfg.b1 * m32 + (1.0 - cfg.b1) * g32
+    v32 = cfg.b2 * v32 + (1.0 - cfg.b2) * jnp.square(g32)
+    mh = m32 / c1
+    vh = v32 / c2
+    delta = mh / (jnp.sqrt(vh) + cfg.eps)
+    if cfg.weight_decay:
+        delta = delta + cfg.weight_decay * p.astype(jnp.float32)
+    newp = (p.astype(jnp.float32) - lr * delta).astype(p.dtype)
+    return newp, _write_moment(m32, cfg.state_dtype), _write_moment(v32, v_dtype)
+
+
 def adamw_update(grads: PyTree, state: dict, params: PyTree, cfg: AdamWConfig):
-    """Returns (new_params, new_state). Grad clip + decoupled weight decay."""
+    """Returns (new_params, new_state). Grad clip + decoupled weight decay.
+
+    The global-norm clip factor is computed outside the per-leaf programs
+    and passed in: a program that couples every gradient through the norm
+    overflowed the stack of XLA's TPU compiler on the chip."""
     step = state["step"] + 1
+    clip = jnp.float32(1.0)
     if cfg.grad_clip_norm is not None:
         gnorm = pytree.global_norm(grads)
         clip = jnp.minimum(1.0, cfg.grad_clip_norm / (gnorm + 1e-9))
-        grads = jax.tree.map(lambda g: g * clip, grads)
 
     lr = cfg.lr(step) if callable(cfg.lr) else cfg.lr
     c1 = 1.0 - cfg.b1 ** step.astype(jnp.float32)
     c2 = 1.0 - cfg.b2 ** step.astype(jnp.float32)
 
-    v_dtype = "bfloat16" if cfg.state_dtype == "int8" else cfg.state_dtype
-
-    def upd(g, m, v, p):
-        g32 = g.astype(jnp.float32)
-        m32 = _read_moment(m, g.shape, cfg.state_dtype)
-        v32 = _read_moment(v, g.shape, v_dtype)
-        m32 = cfg.b1 * m32 + (1.0 - cfg.b1) * g32
-        v32 = cfg.b2 * v32 + (1.0 - cfg.b2) * jnp.square(g32)
-        mh = m32 / c1
-        vh = v32 / c2
-        delta = mh / (jnp.sqrt(vh) + cfg.eps)
-        if cfg.weight_decay:
-            delta = delta + cfg.weight_decay * p.astype(jnp.float32)
-        newp = (p.astype(jnp.float32) - lr * delta).astype(p.dtype)
-        return newp, _write_moment(m32, cfg.state_dtype), _write_moment(v32, v_dtype)
-
     flat_p, treedef = jax.tree.flatten(params)
     flat_g = treedef.flatten_up_to(grads)
     flat_m = treedef.flatten_up_to(state["m"])
     flat_v = treedef.flatten_up_to(state["v"])
-    outs = [upd(g, m, v, p) for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
+    outs = [_leaf_update(g, m, v, p, clip, lr, c1, c2, cfg=cfg)
+            for g, m, v, p in zip(flat_g, flat_m, flat_v, flat_p)]
     new_p = treedef.unflatten([o[0] for o in outs])
     new_m = treedef.unflatten([o[1] for o in outs])
     new_v = treedef.unflatten([o[2] for o in outs])
     return new_p, {"step": step, "m": new_m, "v": new_v}
 
 
+def adamw_step(loss_fn: Callable, cfg: AdamWConfig) -> Callable:
+    """One training step of ``loss_fn(params, *batch)``:
+    ``step(params, state, *batch) -> (params, state, loss)`` — a jitted
+    gradient, then ``adamw_update``. The two are not fused into one
+    program: the global-norm clip would couple every weight gradient's dot
+    to every update, and XLA's TPU compiler overflows its stack estimating
+    that fusion."""
+    grad = jax.jit(jax.value_and_grad(loss_fn))
+
+    def step(params, state, *batch):
+        loss, grads = grad(params, *batch)
+        params, state = adamw_update(grads, state, params, cfg)
+        return params, state, loss
+
+    return step
+
+
 # ---------------------------------------------------------------------------
 # schedules
 # ---------------------------------------------------------------------------
+
 
 def cosine_schedule(peak_lr: float, warmup: int, total: int, floor: float = 0.1):
     def sched(step):

@@ -13,8 +13,9 @@ Usage::
 Columns are referenced by name (resolved against ``TableSchema.scalar_cols``
 at compile time) or by integer index. Atoms are closed ranges ``[lo, hi]``
 over the float32 scalar storage; strict bounds (``<``, ``>``, NOT of a
-range) are exact via ``nextafter`` in float32, so the compiled closed-range
-form evaluates identically to the strict comparison on float32 data.
+range) are exact via ``nextafter`` in float32 (stepping over subnormals,
+which devices flush to zero), so the compiled closed-range form evaluates
+identically to the strict comparison on float32 data.
 
 Compilation pipeline:
   1. push NOT down to the atoms (De Morgan; a negated range splits into at
@@ -50,12 +51,27 @@ def _f32(v) -> float:
     return float(np.float32(v))
 
 
+# XLA on CPU and TPU flushes float32 subnormals to zero, so a strict bound
+# must not land on a subnormal: nextafter(0) = ±1.4e-45 compares equal to
+# 0.0 on the device, and NOT (x == 0) kept the zeros.
+_TINY = np.finfo(np.float32).tiny
+
+
+def _step(v: float, toward: float) -> float:
+    """The float32 next to ``v`` toward ``toward``, as a device that flushes
+    subnormals tells it apart from ``v``: ±tiny from zero, zero from ±tiny."""
+    if v == 0.0:
+        return float(np.copysign(_TINY, toward))
+    r = np.nextafter(np.float32(v), np.float32(toward))
+    return 0.0 if abs(r) < _TINY else float(r)
+
+
 def _next_below(v: float) -> float:
-    return float(np.nextafter(np.float32(v), np.float32(-np.inf)))
+    return _step(v, -np.inf)
 
 
 def _next_above(v: float) -> float:
-    return float(np.nextafter(np.float32(v), np.float32(np.inf)))
+    return _step(v, np.inf)
 
 
 class Expr:

@@ -30,14 +30,39 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.common import compat
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.vectordb.predicates import eval_mask
 from repro.vectordb.table import similarity
 
 NEG = -1e30
+
+
+def place_rows(x: jax.Array, mesh: Mesh, data_axes=("data",)) -> jax.Array:
+    """Put ``x`` on the mesh with its leading (row or shard) axis split over
+    ``data_axes`` and every other axis whole — the layout every shard_map
+    here reads, so a call never has to move table data."""
+    axes = data_axes if isinstance(data_axes, tuple) else (data_axes,)
+    spec = P(axes, *([None] * (x.ndim - 1)))
+    return jax.device_put(x, NamedSharding(mesh, spec))
+
+
+def place_table(table, mesh: Mesh, data_axes=("data",)):
+    """A copy of ``table`` whose columns, scalars and int8 replicas (built
+    here if missing) are row-sharded over the mesh by ``place_rows``:
+    contiguous row blocks, block i on device i, as ``shard_map`` slices
+    them."""
+    from repro.vectordb.table import Table
+
+    for i in range(table.schema.n_vec):
+        table.quantized(i)
+    return Table(
+        schema=table.schema,
+        vectors=[place_rows(v, mesh, data_axes) for v in table.vectors],
+        scalars=place_rows(table.scalars, mesh, data_axes),
+        vectors_i8=[place_rows(v, mesh, data_axes)
+                    for v in table.vectors_i8],
+        scales=[place_rows(s, mesh, data_axes) for s in table.scales])
 
 
 def sharded_masked_scan(mesh: Mesh, data_axes=("data",), *, k: int, n_vec: int,
@@ -69,7 +94,7 @@ def sharded_masked_scan(mesh: Mesh, data_axes=("data",), *, k: int, n_vec: int,
         return out_ids, ms
 
     vec_specs = tuple(P(axes, None) for _ in range(n_vec))
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(vec_specs, P(axes, None), P(), tuple(P() for _ in range(n_vec)), P(), P(axes)),
@@ -139,7 +164,7 @@ def sharded_masked_scan_batched(mesh: Mesh, data_axes=("data",), *, k: int,
 
     vec_specs = tuple(P(axes, None) for _ in range(n_vec))
     scale_specs = tuple(P(axes) for _ in range(n_vec)) if int8 else P()
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(vec_specs, scale_specs, P(axes, None), P(),
@@ -244,7 +269,7 @@ def sharded_batch_topk(mesh: Mesh, data_axes=("data",), *, k: int):
         g_all = jax.lax.all_gather(gids, axes, axis=1, tiled=True)
         return _merge_shard_candidates(s_all, g_all, k=k)
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(None, axes), P(axes, None), P(), P(axes)),
@@ -276,7 +301,7 @@ class ShardedIVF:
     shard axis so a single structure serves both execution modes: under
     ``shard_map`` axis 0 shards across the mesh's data axes (each device
     reads only its own shard's index), and the logical single-device path
-    vmaps over it with identical semantics.
+    loops over it with identical semantics.
 
     Rows are the table's contiguous ``shard_len``-sized slices.
     ``sorted_rows`` holds LOCAL row ids (0 .. shard_rows-1); callers
@@ -310,6 +335,12 @@ class ShardedIVF:
     @property
     def shard_len(self) -> int:
         return int(self.sorted_rows.shape[1])
+
+    def placed(self, mesh: Mesh, data_axes=("data",)) -> "ShardedIVF":
+        """This index with shard s's arrays on the mesh's device s."""
+        return ShardedIVF(*(place_rows(x, mesh, data_axes) for x in
+                            (self.centroids, self.sorted_rows, self.offsets)),
+                          metric=self.metric)
 
     def local_index(self, s: int):
         """Shard ``s``'s index as a plain ``ivf.IVFIndex`` (tests, probes)."""
@@ -385,7 +416,7 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
     starved probe), not the data, was the binding constraint and rows it
     never surfaced may belong in the global top-k — the loss mode the old
     merged-underfill trigger could never see. Without a mesh the shard
-    axis is vmapped on one device (a non-divisible table is zero-padded;
+    axis is looped on one device (a non-divisible table is zero-padded;
     padded rows are unreachable by construction); with a mesh the identical
     body runs under ``shard_map`` and the merge is one all-gather, in the
     same shard order.
@@ -459,10 +490,12 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
                             for v in vectors)
             scal_sh = scalars.reshape(s, shard_len, scalars.shape[1])
             row0 = jnp.arange(s, dtype=jnp.int32) * shard_len
-            ids, sc, fill, bnd = jax.vmap(
-                body, in_axes=(0, 0, 0, 0, 0, 0, None, None, None))(
-                cent_t, rows_t, offs_t, vecs_sh, scal_sh, row0,
-                pred_b, qv_t, w_b)
+            # a loop over the shard axis, not vmap: the gather kernel reads
+            # its table from HBM (memory space ANY), which Mosaic cannot
+            # batch
+            ids, sc, fill, bnd = jax.lax.map(
+                lambda xs: body(*xs, pred_b, qv_t, w_b),
+                (cent_t, rows_t, offs_t, vecs_sh, scal_sh, row0))
             b = sc.shape[1]
             # (S, B, k) -> (B, S·k) in shard order — the all_gather layout
             s_all = jnp.swapaxes(sc, 0, 1).reshape(b, s * k)
@@ -487,7 +520,7 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
         mi, ms = _merge_shard_candidates(s_all, g_all, k=k)
         return mi, ms, fill[None, :], bnd[None, :]
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(sub_specs3, sub_specs2, sub_specs2, vec_specs,
                   P(axes, None), P(), tuple(P() for _ in range(n_cols)),

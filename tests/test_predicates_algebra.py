@@ -117,6 +117,20 @@ def test_property_eval_matches_numpy_oracle(seed):
     _check_tree(random_expr(rng, scal), scal)
 
 
+def test_strict_bounds_at_zero_exclude_zero():
+    """NOT (x == 0), x < 0 and x > 0 exclude the zeros: the strict bounds
+    step over float32 subnormals, which the device flushes to zero (a
+    bound of ±1.4e-45 compared equal to 0.0). Seed 63601 of the property
+    test above found it."""
+    scal = np.asarray([[0.0], [-1.0], [2.0], [0.0]], np.float32)
+    for expr, want in ((~(col(0) == 0.0), [False, True, True, False]),
+                       (col(0) < 0.0, [False, True, False, False]),
+                       (col(0) > 0.0, [False, False, True, False])):
+        got = np.asarray(eval_mask(expr.compile(m=1), jnp.asarray(scal)))
+        np.testing.assert_array_equal(got, want)
+        np.testing.assert_array_equal(np_eval(expr, scal), want)
+
+
 # ---------------------------------------------------------------------------
 # builder / compilation specifics
 # ---------------------------------------------------------------------------

@@ -305,3 +305,29 @@ def test_async_engine_timeout_disposition():
     assert r.status == TIMED_OUT and r.result is None
     rep = eng.report()
     assert rep.n_timed_out == 1 and rep.p50_ms is None
+
+
+def test_compaction_failure_surfaces_at_drain():
+    """A compaction that raises on the worker thread stops further
+    compactions and re-raises from drain() (so an engine's stop() fails),
+    instead of vanishing with its future."""
+    from repro.serve.queue import CompactionScheduler
+
+    class FailingTiered:
+        n_calls = 0
+
+        def needs_compaction(self):
+            return True
+
+        def compact(self):
+            self.n_calls += 1
+            raise RuntimeError("compaction broke")
+
+    tiered = FailingTiered()
+    sched = CompactionScheduler(tiered)
+    assert sched.maybe_schedule()
+    sched._inflight.exception()  # wait for the worker to finish
+    assert not sched.maybe_schedule()  # a failed compaction is final
+    with pytest.raises(RuntimeError, match="compaction broke"):
+        sched.drain()
+    assert tiered.n_calls == 1
