@@ -20,7 +20,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops, ref
-from repro.kernels.gather_score import gather_score_topk, gather_score_topk_int8
+from repro.kernels.gather_score import (
+    GatherRows, gather_score_topk, gather_score_topk_int8,
+)
 
 NEG = -1e30
 
@@ -77,20 +79,22 @@ def crossover_sweep(n: int = 60_000, d: int = 128, b: int = 32, m: int = 3,
     hi = jnp.asarray([8.0] + [np.inf] * (m - 1), jnp.float32)
     ms_dense = _timeit(lambda: dense(q_b, lo, hi))
 
+    table_rows = GatherRows.build((vecs,), scal)
     if precision == "int8":
         v8, sc8 = ops.quantize_rows(vecs)
+        rows_i8 = GatherRows.build((v8,), scal, (sc8,), meta=table_rows.meta)
 
         @jax.jit
         def local_fn(c):
             return gather_score_topk_int8(
-                c, (vecs,), (v8,), (sc8,), (q_b,), w_b, scal, pred_b,
+                c, table_rows, rows_i8, (q_b,), w_b, pred_b,
                 k=k, metric="dot", use_kernel=False)
     else:
         @jax.jit
         def local_fn(c):
             # jitted like the serving paths (gather_score_topk is traceable
             # and always called inside the executor's jitted graphs)
-            return gather_score_topk(c, (vecs,), (q_b,), w_b, scal, pred_b,
+            return gather_score_topk(c, table_rows, (q_b,), w_b, pred_b,
                                      k=k, metric="dot", use_kernel=False)
 
     rows = []

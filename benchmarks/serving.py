@@ -809,7 +809,7 @@ def run_graph(*, rows: int = 100_000, n_hard: int = 48, batch_size: int = 16,
         nv = []
         for q in hard[:m]:
             _, _, nvis, _ = graph.search(
-                g, table.vectors[0], table.scalars, q.predicates,
+                g, table.gather_rows((0,)), q.predicates,
                 q.query_vectors[0], beam_width=bw, n_hops=nh, k=k)
             nv.append(int(nvis))
         return int(np.mean(nv))

@@ -22,7 +22,8 @@ the backend, the device kind, whether its jitted groups' compiled text holds
 the Mosaic kernel (``tpu_custom_call``), compile seconds and requests served.
 
 ``--chips 4`` binds the same table to a 4-device mesh
-(``bind_shards(4, mesh=...)``), prints where each column's rows live, and
+(``bind_shards(4, mesh=...)``), prints where each column's rows live,
+serves 8 conjunctive and 8 DNF requests (no bucket-ladder warm-up), and
 compares the served ids and scores with logical shards on one device and
 with the sharded oracle.
 
@@ -52,6 +53,7 @@ sys.path[:0] = [str(ROOT / "src"), str(ROOT / "tests")]
 PART_ROWS = 200_000  # bench/datasets.py SPECS["part"].paper_rows
 TRAIN_QUERIES = 8  # the fit's training workload
 N_CONJ, N_DNF = 16, 16  # served requests per set: conjunctive, DNF
+N_SHARD = 8  # --chips 4: conjunctive and DNF requests each
 BATCH_SIZE = 8
 EXACT_FLOOR = 1.0  # exact paths: every request, up to float ties
 LOCAL_FLOOR = 0.95  # generous-budget candidate-local mean (test_oracle.py)
@@ -254,14 +256,15 @@ def build(rows: int, seed: int, n_train: int, cfg=None, clock=None):
     return bq, time.perf_counter() - t_all
 
 
-def serve(bq, reqs, batch_size: int) -> list:
-    """warm_bucket_ladder, then the requests through AsyncServingEngine /
-    serve_stream. -> [(ids, scores)] in request order; raises unless every
-    request resolved ok."""
+def serve(bq, reqs, batch_size: int, *, warm: bool = True) -> list:
+    """warm_bucket_ladder (unless not ``warm``), then the requests through
+    AsyncServingEngine / serve_stream. -> [(ids, scores)] in request order;
+    raises unless every request resolved ok."""
     from repro.serve.batch import warm_bucket_ladder
     from repro.serve.queue import OK, AsyncServingEngine, serve_stream
 
-    warm_bucket_ladder(bq.execute_batch, reqs, batch_size)
+    if warm:
+        warm_bucket_ladder(bq.execute_batch, reqs, batch_size)
     engine = AsyncServingEngine(bq, batch_size=batch_size, max_wait=0.01)
     served = asyncio.run(serve_stream(engine, reqs))
     bad = [(r.seq, r.status) for r in served if r.status != OK]
@@ -367,7 +370,9 @@ def shard_phase(bq, reqs, masked, ht, *, n_chips, batch_size):
     """Serve ``reqs`` over a ``n_chips``-device mesh and over logical
     shards, for the exact sharded scan and the per-shard probing route;
     compare ids/scores between the two and, for the exact scan, with the
-    sharded oracle. -> stats per route."""
+    sharded oracle. -> stats per route. No bucket-ladder warm-up: it would
+    compile every batch bucket of both routes, on and off the mesh, and
+    only the served buckets are compared."""
     import jax
     from jax.sharding import Mesh
 
@@ -389,10 +394,10 @@ def shard_phase(bq, reqs, masked, ht, *, n_chips, batch_size):
                     f"[{describe_devices(v)}]")
             log(f"  scalars: [{describe_devices(placed.scalars)}]; int8 "
                 f"replica 0: [{describe_devices(placed.vectors_i8[0])}]")
-            on_mesh = serve(bq, reqs, batch_size)
+            on_mesh = serve(bq, reqs, batch_size, warm=False)
             mesh_s = time.perf_counter() - t0
             bq.bind_shards(n_chips)
-            logical = serve(bq, reqs, batch_size)
+            logical = serve(bq, reqs, batch_size, warm=False)
             bq.bind_shards()
         recalls = check_results(reqs, on_mesh, masked)
         check_results(reqs, logical, masked)
@@ -429,8 +434,6 @@ def shard_phase(bq, reqs, masked, ht, *, n_chips, batch_size):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--chips", type=int, default=1, choices=(1, 4))
-    ap.add_argument("--rows", type=int, default=PART_ROWS,
-                    help="rows of part (a cut is printed)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
@@ -446,8 +449,6 @@ def main(argv=None) -> int:
         return 1
     log(f"device: {dev.platform} {dev.device_kind} x{len(devs)}; compile "
         f"cache {place_compile_cache()}")
-    if args.rows != PART_ROWS:
-        log(f"rows cut: {args.rows} of part's {PART_ROWS}")
     failures = []
     try:
         clock = CompileClock()
@@ -456,21 +457,24 @@ def main(argv=None) -> int:
             from repro.core.boomhq import BoomHQ, BoomHQConfig
 
             t0 = time.perf_counter()
-            table = datasets.make("part", rows=args.rows, seed=args.seed)
+            table = datasets.make("part", rows=PART_ROWS, seed=args.seed)
             # no fit: the exact sharded scan ignores plans, and the probing
             # route runs the default plans; the graph tier is single-device
             bq = BoomHQ(table, BoomHQConfig(graph_degree=0))
             setup = time.perf_counter() - t0
         else:
-            bq, setup = build(args.rows, args.seed, TRAIN_QUERIES,
+            bq, setup = build(PART_ROWS, args.seed, TRAIN_QUERIES,
                               clock=clock)
             table = bq.table
         t0 = time.perf_counter()
         ht = host_table(table)
-        sets = {"mixed": make_requests(table, N_CONJ, N_DNF, args.seed)}
-        if args.chips == 1:
-            sets["single"] = make_requests(table, N_CONJ, N_DNF,
-                                           args.seed, n_vec_used=1)
+        if args.chips == 4:
+            sets = {"mixed": make_requests(table, N_SHARD, N_SHARD,
+                                           args.seed)}
+        else:
+            sets = {"mixed": make_requests(table, N_CONJ, N_DNF, args.seed),
+                    "single": make_requests(table, N_CONJ, N_DNF, args.seed,
+                                            n_vec_used=1)}
         masks = {k: oracle_masks(ht, v) for k, v in sets.items()}
         log(f"setup: {table.n_rows} rows x {len(table.vectors)} columns, "
             f"{setup:.1f}s ({clock.lap()}); oracle for "

@@ -144,7 +144,7 @@ def run_trace_checks(cfg: LintConfig) -> list:
     except Exception:  # pragma: no cover - jax-less checkout
         return findings
 
-    from repro.kernels.gather_score import gather_score_topk
+    from repro.kernels.gather_score import GatherRows, gather_score_topk
     from repro.launch import hlo_analysis
     from repro.vectordb import flat, ivf
     from repro.vectordb.distributed import (
@@ -152,6 +152,7 @@ def run_trace_checks(cfg: LintConfig) -> list:
     )
 
     vectors, scalars, q_b, pred_b, w_b = _fixture()
+    rows = GatherRows.build((vectors,), scalars)
     k = 8
 
     # gather_score: reference path (the off-TPU executor scoring path) and
@@ -160,17 +161,17 @@ def run_trace_checks(cfg: LintConfig) -> list:
     for label, use_kernel in (("gather_score_ref", False),
                               ("gather_score_kernel", True)):
         jaxpr = jax.make_jaxpr(
-            lambda c, v, s, q, w, p: gather_score_topk(
-                c, (v,), (q,), w, s, p, k=k, use_kernel=use_kernel,
-                interpret=True))(cand, vectors, scalars, q_b, w_b, pred_b)
+            lambda c, r, q, w, p: gather_score_topk(
+                c, r, (q,), w, p, k=k, use_kernel=use_kernel,
+                interpret=True))(cand, rows, q_b, w_b, pred_b)
         _check_jaxpr(findings, label, "src/repro/kernels/gather_score.py",
                      prim_counts(jaxpr.jaxpr), cfg, allow_gathers=0)
 
     # batched filter-first (candidate-local, no dense matrix)
     jaxpr = jax.make_jaxpr(
-        lambda v, s, p, q, w: flat.filter_first_local_batch(
-            (v,), s, p, (q,), w, k=k, max_candidates=64, n_vec=1))(
-        vectors, scalars, pred_b, q_b, w_b)
+        lambda r, p, q, w: flat.filter_first_local_batch(
+            r, p, (q,), w, k=k, max_candidates=64, n_vec=1))(
+        rows, pred_b, q_b, w_b)
     _check_jaxpr(findings, "filter_first_local_batch",
                  "src/repro/vectordb/flat.py", prim_counts(jaxpr.jaxpr),
                  cfg, allow_gathers=0)
@@ -178,9 +179,9 @@ def run_trace_checks(cfg: LintConfig) -> list:
     # plan-driven IVF probing (single-index batched path)
     index = ivf.build(vectors, 8, seed=0)
     jaxpr = jax.make_jaxpr(
-        lambda v, s, p, q: ivf.search_local_batch(
-            index, v, s, p, q, nprobe=2, max_scan=64, k=k))(
-        vectors, scalars, pred_b, q_b)
+        lambda r, p, q: ivf.search_local_batch(
+            index, r, p, q, nprobe=2, max_scan=64, k=k))(
+        rows, pred_b, q_b)
     _check_jaxpr(findings, "search_local_batch",
                  "src/repro/vectordb/ivf.py", prim_counts(jaxpr.jaxpr),
                  cfg, allow_gathers=0)
@@ -216,13 +217,12 @@ def run_trace_checks(cfg: LintConfig) -> list:
     # plan-driven per-shard IVF probing, logical-shard path (vmap): must be
     # collective- and callback-free
     sivf = build_sharded_ivf(vectors, 2, n_clusters=8)
-    sfn = sharded_ivf_topk(2, None, subs=((0, 8, 16, 2, 64),), k=k,
-                           n_cols=1, metric="dot", pad_total=64)
+    sfn = sharded_ivf_topk(2, None, subs=((0, 8, 16, 2, 64, True),), k=k,
+                           metric="dot", pad_total=64)
     jaxpr = jax.make_jaxpr(
-        lambda c, r, o, v, s, p, q, w: sfn((c,), (r,), (o,), (v,), s, p,
-                                           (q,), w))(
-        sivf.centroids, sivf.sorted_rows, sivf.offsets, vectors, scalars,
-        pred_b, q_b, w_b)
+        lambda c, r, o, g, p, q, w: sfn((c,), (r,), (o,), g, p, (q,), w))(
+        sivf.centroids, sivf.sorted_rows, sivf.offsets, rows, pred_b, q_b,
+        w_b)
     _check_jaxpr(findings, "sharded_ivf_topk",
                  "src/repro/vectordb/distributed.py",
                  prim_counts(jaxpr.jaxpr), cfg, allow_gathers=0)

@@ -308,7 +308,7 @@ class HybridExecutor:
                 # graph; the returned list is already filtered + ranked,
                 # so it slots into the same RRF union + rerank as IVF
                 ids_i, _, _, _ = graph.search(
-                    self.graphs[i], t.vectors[i], t.scalars, q.predicates,
+                    self.graphs[i], t.gather_rows((i,)), q.predicates,
                     q.query_vectors[i], beam_width=plan.beam_width,
                     n_hops=plan.n_hops, k=ks)
             else:
@@ -350,9 +350,9 @@ class HybridExecutor:
             nprobe = min(nprobe, self.indexes[i].n_clusters, self.engine.nprobe_cap)
             max_scan = min(sp.max_scan, t.n_rows)
             if precision == "int8":
-                vq, sc = t.quantized(i)
                 ids_b, _, _, nq_b = ivf.search_local_batch_int8(
-                    self.indexes[i], t.vectors[i], vq, sc, t.scalars,
+                    self.indexes[i], t.gather_rows((i,)),
+                    t.gather_rows((i,), int8=True),
                     predicates.stack([q.predicates]),
                     q.query_vectors[i][None, :],
                     nprobe=nprobe, max_scan=max_scan, k=kw)

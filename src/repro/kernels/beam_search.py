@@ -185,8 +185,7 @@ def beam_candidates_batch(neighbors, vectors, scalars, entry, pred_b, q_b, *,
 def beam_search_topk(
     neighbors: jax.Array,  # (n, r) i32 adjacency, -1 = free slot
     entry: jax.Array,  # (E,) i32 entry points
-    vectors: jax.Array,  # (n, d) the indexed column
-    scalars: jax.Array,  # (n, M)
+    rows,  # GatherRows of the indexed column
     pred_b: PredicateLike,  # stacked, leading axis B
     q_b: jax.Array,  # (B, d)
     *,
@@ -220,6 +219,7 @@ def beam_search_topk(
     rather than at its lowest row ids — the walk then hill-climbs from
     the best of them. Empty segments pad with -1 and are ignored by the
     walk."""
+    vectors, scalars = rows.vectors[0], rows.scalars
     n = scalars.shape[0]
     n_seeds = GRAPH_SEED_FACTOR * beam_width
     seg = -(-n // n_seeds)
@@ -249,6 +249,6 @@ def beam_search_topk(
         beam_width=beam_width, n_hops=n_hops, metric=metric)
     w = jnp.ones((q_b.shape[0], 1), jnp.float32)
     ids, scores, n_qual = gather_score_topk(
-        cand, (vectors,), (q_b,), w, scalars, pred_b, k=k, metric=metric,
+        cand, rows, (q_b,), w, pred_b, k=k, metric=metric,
         use_kernel=use_kernel, interpret=interpret, block_s=block_s)
     return ids, scores, n_visited, n_qual

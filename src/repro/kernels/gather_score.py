@@ -9,11 +9,12 @@ query. This kernel closes that gap. Given a ``(B, S)`` candidate-row matrix
 (padded with -1), each grid step (query b, candidate block j):
 
   * copies the block's candidate ids into SMEM, then gathers each
-    candidate's row of the table's ``(n, 1, W)`` row view (every weighted
-    column plus the scalars, ``gather_view``) into VMEM scratch with one
-    HBM→VMEM async copy per row — the table stays in HBM, so its size is
-    bounded by HBM, not the ~16 MB VMEM; the rows are arbitrary, so there
-    is no contiguous BlockSpec for them;
+    candidate's row of every column's ``(n, 1, W)`` row view plus the
+    scalars' row view (``GatherRows``, built once per table version by its
+    owner) into VMEM scratch with one HBM→VMEM async copy per row and view
+    — the table stays in HBM, so its size is bounded by HBM, not the
+    ~16 MB VMEM, and a call moves only its candidates' rows; the rows are
+    arbitrary, so there is no contiguous BlockSpec for them;
   * scores each column with f32 VPU products and lane reductions (no
     reduced-precision MXU pass) and combines with the query's column
     weights (l2 keeps the -||v||² and -||q||² terms so score VALUES match
@@ -38,6 +39,7 @@ forces either path (tests pin kernel-vs-reference parity with
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -94,13 +96,21 @@ def merge_topk_unique(ids, scores, k: int):
 # ---------------------------------------------------------------------------
 
 def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
-            view_hbm, out_s_ref, out_i_ref, out_q_ref, ids_smem, cid_tile,
-            tile_ref, sem, *, k: int, k_pad: int, block_s: int, nb: int,
-            segs: tuple, n_clauses: int, metric: str, apply_pred: bool,
-            int8: bool):
+            *refs, k: int, k_pad: int, block_s: int, nb: int, dims: tuple,
+            n_clauses: int, metric: str, apply_pred: bool, int8: bool):
     """One (query b, candidate block j) grid step. Every per-candidate
     quantity is a (block_s, 1) column; each output is one lane-dense
-    (1, k_pad) row, so no scalar is ever stored into a vector ref."""
+    (1, k_pad) row, so no scalar is ever stored into a vector ref.
+
+    ``refs``: the per-column row views and the meta view (HBM), the three
+    outputs, then scratch: SMEM ids, the id column, one row tile per view
+    and the DMA semaphores."""
+    n_vec = len(dims)
+    view_hbm = refs[:n_vec + 1]  # columns..., meta
+    out_s_ref, out_i_ref, out_q_ref = refs[n_vec + 1:n_vec + 4]
+    ids_smem, cid_tile = refs[n_vec + 4:n_vec + 6]
+    tiles = refs[n_vec + 6:2 * n_vec + 7]
+    sem = refs[2 * n_vec + 7]
     # the block's candidate ids land in SMEM, so every row copy below
     # takes a scalar address
     blk = pl.program_id(0) * nb + pl.program_id(1)
@@ -108,20 +118,21 @@ def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
     ids_copy.start()
     ids_copy.wait()
 
-    def row_copy(t, r):
-        return pltpu.make_async_copy(view_hbm.at[pl.ds(r, 1)],
-                                     tile_ref.at[pl.ds(t, 1)], sem.at[1])
+    def row_copies(t, r):
+        return [pltpu.make_async_copy(v.at[pl.ds(r, 1)],
+                                      tile.at[pl.ds(t, 1)], sem.at[1 + c])
+                for c, (v, tile) in enumerate(zip(view_hbm, tiles))]
 
-    # arbitrary-row gather: one HBM->VMEM copy per valid candidate row (all
-    # of its columns and scalars in one view row), all started before any
-    # is waited on. The table never enters VMEM, so its size is bounded by
-    # HBM; padding slots (-1) move nothing.
+    # arbitrary-row gather: one HBM->VMEM copy per valid candidate row and
+    # view, all started before any is waited on. The table never enters
+    # VMEM, so its size is bounded by HBM; padding slots (-1) move nothing.
     def start(t, _):
         r = ids_smem[0, t]
 
         @pl.when(r >= 0)
         def _():
-            row_copy(t, r).start()
+            for cp in row_copies(t, r):
+                cp.start()
         return 0
 
     def wait(t, _):
@@ -129,7 +140,8 @@ def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
 
         @pl.when(r >= 0)
         def _():
-            row_copy(t, r).wait()
+            for cp in row_copies(t, r):
+                cp.wait()
         cid_tile[pl.ds(t, 1), :] = jnp.full((1, 128), r, jnp.int32)
         return 0
 
@@ -138,26 +150,29 @@ def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
 
     cid = cid_tile[:, 0:1]  # (BS, 1) i32, -1 = padding
     valid = cid >= 0
-    tile = tile_ref[...]
-    tile = tile.reshape(block_s, tile.shape[-1])
-    meta = tile[:, -128:]  # [scalars | int8 scales | 0-pad], 128 lanes
-    if int8:
-        meta = jax.lax.bitcast_convert_type(meta, jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 128), 1)
+    meta = tiles[n_vec][...].reshape(block_s, 128)  # [scalars | 0-pad]
     w = w_ref[0]  # (1, n_vec)
     total = jnp.zeros((block_s, 1), jnp.float32)
     off = 0
-    for i, (width, scale_lane) in enumerate(segs):
-        seg = tile[:, off:off + width]
-        q = q_ref[0, :, off:off + width]  # (1, P) f32 or (4, P) for int8
+    for i, d in enumerate(dims):
+        seg = tiles[i][...]
+        width = seg.shape[-1]
+        seg = seg.reshape(block_s, width)
+        q = q_ref[0, :, off:off + width]  # (1, W) f32 or (4, W) for int8
         off += width
         # VPU multiplies + lane reductions: exact f32 products (no
         # reduced-precision MXU passes), so score VALUES match
         # ``table.similarity``; zero lane padding adds nothing
         if int8:
-            # quantized tier: the segment holds d/4 words of 4 packed int8
+            # quantized tier: the row holds d/4 words of 4 packed int8
             # lanes (byte p of word m is element 4m+p; q is regrouped the
-            # same way) — a quarter of the fp32 bytes per gathered row
+            # same way) — a quarter of the fp32 bytes per gathered row —
+            # then the row's dequant scale as f32 bits in word d/4
+            d4 = -(-d // 4)
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, width), 1)
+            sc = jnp.sum(jnp.where(lane == d4, jax.lax.bitcast_convert_type(
+                seg, jnp.float32), 0.0), axis=1, keepdims=True)
+            seg = jnp.where(lane < d4, seg, 0)
             s = jnp.zeros((block_s, 1), jnp.float32)
             nn = jnp.zeros((block_s, 1), jnp.float32)
             for p in range(4):
@@ -165,8 +180,6 @@ def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
                 s = s + jnp.sum(e * q[p:p + 1], axis=1, keepdims=True)
                 nn = nn + jnp.sum(e * e, axis=1, keepdims=True)
             # score(v·s) = s·score(v); l2 norms rescale by s²
-            sc = jnp.sum(jnp.where(lane == scale_lane, meta, 0.0), axis=1,
-                         keepdims=True)
             s = s * sc
             nn = nn * sc * sc
         else:
@@ -178,7 +191,7 @@ def _kernel(cand_hbm, w_ref, lo_ref, hi_ref, act_ref, cval_ref, q_ref,
 
     ok = valid
     if apply_pred:
-        # lo/hi/active are padded to the meta segment's 128 lanes with
+        # lo/hi/active are padded to the meta view's 128 lanes with
         # inactive columns, so lanes past the scalars always pass
         any_clause = jnp.zeros((block_s, 1), bool)
         for c in range(n_clauses):
@@ -221,53 +234,94 @@ def block_topk(s, ids, *, k: int, k_pad: int):
     return row_s, row_i
 
 
-def gather_view(vectors, scalars, scales=None):
-    """The kernel's (n, 1, W) row view of a table: per vector column one
-    lane-aligned segment, then a 128-lane meta segment [scalars | per-column
-    int8 dequant scales | 0]. Mosaic copies rows only along a leading axis
-    and in whole 128-lane tiles, so one view row is one DMA that brings a
-    candidate's every column and scalar at once.
+@jax.jit
+def column_view(v):
+    """fp32 row view of one (n, d) column: (n, 1, d rounded up to 128
+    lanes), zero-padded."""
+    n, d = v.shape
+    return jnp.pad(v.astype(jnp.float32),
+                   ((0, 0), (0, round128(d) - d)))[:, None, :]
 
-    fp32: segments are the f32 rows zero-padded to 128 lanes. int8
-    (``scales`` given, ``vectors`` the int8 replicas): segments hold four
-    int8 lanes per i32 word (byte p of word m is element 4m+p), and the
-    whole view is i32 with the meta segment as f32 bits.
-    -> (view, segs) with segs = ((width, meta scale lane or -1), ...)."""
-    n, m = scalars.shape
-    n_vec = len(vectors)
-    assert m + n_vec <= 128, (m, n_vec)
-    parts, segs = [], []
-    for i, v in enumerate(vectors):
+
+@jax.jit
+def int8_view(v, scale):
+    """int8 row view of one (n, d) replica: four int8 lanes per i32 word
+    (byte p of word m is element 4m+p), then the row's dequant scale as
+    f32 bits in word d/4, zero-padded to 128 lanes."""
+    n, d = v.shape
+    d4 = -(-d // 4)
+    b = jnp.pad(v, ((0, 0), (0, 4 * d4 - d)))
+    b = (b.astype(jnp.int32) & 0xFF).reshape(n, d4, 4)
+    words = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) \
+        | (b[..., 3] << 24)
+    scale = jax.lax.bitcast_convert_type(scale.astype(jnp.float32),
+                                         jnp.int32)[:, None]
+    row = jnp.concatenate([words, scale], axis=1)
+    return jnp.pad(row, ((0, 0), (0, round128(d4 + 1) - d4 - 1)))[:, None, :]
+
+
+@jax.jit
+def meta_view(scalars):
+    """(n, 1, 128) row view of the (n, M) scalars, zero-padded."""
+    m = scalars.shape[1]
+    assert m <= 128, m
+    return jnp.pad(scalars.astype(jnp.float32),
+                   ((0, 0), (0, 128 - m)))[:, None, :]
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class GatherRows:
+    """A table's columns as the candidate-local scorers read them: the
+    plain arrays (routing, predicate masks and the reference path) and the
+    kernel's row views. Mosaic copies rows only along a leading axis and
+    in whole 128-lane tiles, so each column is kept as an (n, 1, W) view
+    whose row is one DMA, and the scalars as an (n, 1, 128) view.
+
+    Views cost one copy of the table per version: the owner (``Table``,
+    a hot view, a mesh placement) builds them once and every call reads
+    only its candidates' rows. ``build`` makes them from plain arrays, for
+    callers that hold no owner (tests, benchmarks).
+
+    fp32 rows carry f32 columns; int8 rows (``scales`` given) carry the
+    int8 replicas and per-row dequant scales, their views packed words
+    (``int8_view``)."""
+
+    vectors: tuple  # (n, d_i) per column: f32, or the int8 replicas
+    scalars: jax.Array  # (n, M) f32
+    scales: tuple | None  # (n,) f32 per-row dequant scales (int8 only)
+    views: tuple  # (n, 1, W_i) per column
+    meta: jax.Array  # (n, 1, 128) f32 scalars
+
+    @staticmethod
+    def build(vectors, scalars, scales=None, meta=None) -> "GatherRows":
+        vectors = tuple(vectors)
         if scales is None:
-            width = round128(v.shape[1])
-            parts.append(jnp.pad(v.astype(jnp.float32),
-                                 ((0, 0), (0, width - v.shape[1]))))
-            segs.append((width, -1))
+            views = tuple(column_view(v) for v in vectors)
         else:
-            d4 = -(-v.shape[1] // 4)
-            width = round128(d4)
-            b = jnp.pad(v, ((0, 0), (0, 4 * d4 - v.shape[1])))
-            b = (b.astype(jnp.int32) & 0xFF).reshape(n, d4, 4)
-            words = b[..., 0] | (b[..., 1] << 8) | (b[..., 2] << 16) \
-                | (b[..., 3] << 24)
-            parts.append(jnp.pad(words, ((0, 0), (0, width - d4))))
-            segs.append((width, m + i))
-    meta = [scalars.astype(jnp.float32)]
-    if scales is not None:
-        meta += [s.astype(jnp.float32)[:, None] for s in scales]
-    meta = jnp.concatenate(meta, axis=1)
-    meta = jnp.pad(meta, ((0, 0), (0, 128 - meta.shape[1])))
-    if scales is not None:
-        meta = jax.lax.bitcast_convert_type(meta, jnp.int32)
-    return jnp.concatenate(parts + [meta], axis=1)[:, None, :], tuple(segs)
+            scales = tuple(scales)
+            views = tuple(int8_view(v, s) for v, s in zip(vectors, scales))
+        return GatherRows(vectors, scalars, scales, views,
+                          meta_view(scalars) if meta is None else meta)
+
+    def select(self, cols) -> "GatherRows":
+        """The same rows restricted to columns ``cols``."""
+        pick = lambda xs: tuple(xs[c] for c in cols)
+        return GatherRows(pick(self.vectors), self.scalars,
+                          None if self.scales is None else pick(self.scales),
+                          pick(self.views), self.meta)
+
+    @property
+    def int8(self) -> bool:
+        return self.scales is not None
 
 
-def _query_view(qs, segs, int8: bool):
-    """(B, R, sum of segment widths) queries laid out like the view's
-    vector segments: R = 1 row of zero-padded f32 lanes, or for int8 R = 4
-    rows where row p holds the elements 4m+p the packed byte p multiplies."""
+def _query_view(qs, widths, int8: bool):
+    """(B, R, sum of view widths) queries laid out like the row views:
+    R = 1 row of zero-padded f32 lanes, or for int8 R = 4 rows where row p
+    holds the elements 4m+p the packed byte p multiplies."""
     parts = []
-    for q, (width, _) in zip(qs, segs):
+    for q, width in zip(qs, widths):
         q = q.astype(jnp.float32)
         b, d = q.shape
         if int8:
@@ -281,8 +335,8 @@ def _query_view(qs, segs, int8: bool):
 
 @functools.partial(jax.jit, static_argnames=("k", "block_s", "metric",
                                              "apply_pred", "interpret"))
-def gather_score_blocks(cand, vectors, qs, weights, scalars, lo, hi, active,
-                        clause_valid, scales=None, *, k: int, block_s: int,
+def gather_score_blocks(cand, rows: GatherRows, qs, weights, lo, hi, active,
+                        clause_valid, *, k: int, block_s: int,
                         metric: str = "dot", apply_pred: bool = True,
                         interpret: bool = True):
     """-> (block_scores (B, nb, k), block_ids (B, nb, k), block_qual (B, nb)).
@@ -290,29 +344,28 @@ def gather_score_blocks(cand, vectors, qs, weights, scalars, lo, hi, active,
     ``cand`` (B, S) i32 candidate rows (-1 = padding), S a multiple of
     ``block_s``; block ids are ROW ids (block-locally deduplicated).
 
-    With ``scales`` (tuple of (n,) f32 per-row dequant scales) the
-    ``vectors`` are the int8 replicas: rows gather as packed int8 words and
-    dequantize per row in VMEM — the quantized scoring tier.
+    Int8 ``rows`` (``rows.scales`` given) hold the int8 replicas: rows
+    gather as packed int8 words and dequantize per row in VMEM — the
+    quantized scoring tier.
 
-    Layout for Mosaic: the table enters as one (n, 1, W) row view
-    (``gather_view``, built inside the caller's jit), candidate ids as
-    lane-padded (B·nb, 1, block_s) rows copied into SMEM, per-query operands as
-    (B, R, X) arrays whose blocks span their trailing dims, and each output
-    block is a lane-dense (1, k_pad) row (k_pad = k rounded up to 128),
-    sliced back to k here."""
+    Layout for Mosaic: the table enters as ``rows``' (n, 1, W) row views,
+    read in place from HBM; candidate ids as lane-padded (B·nb, 1, block_s)
+    rows copied into SMEM; per-query operands as (B, R, X) arrays whose
+    blocks span their trailing dims; and each output block is a lane-dense
+    (1, k_pad) row (k_pad = k rounded up to 128), sliced back to k here."""
     b, s_tot = cand.shape
     assert s_tot % block_s == 0, (s_tot, block_s)
     nb = s_tot // block_s
-    n, m = scalars.shape
-    int8 = scales is not None
-    view, segs = gather_view(vectors, scalars, scales)
-    q3 = _query_view(qs, segs, int8)
+    m = rows.scalars.shape[1]
+    widths = tuple(v.shape[-1] for v in rows.views)
+    dims = tuple(q.shape[1] for q in qs)
+    q3 = _query_view(qs, widths, rows.int8)
     k_pad = round128(k)
     kern = functools.partial(
-        _kernel, k=k, k_pad=k_pad, block_s=block_s, nb=nb, segs=segs,
+        _kernel, k=k, k_pad=k_pad, block_s=block_s, nb=nb, dims=dims,
         n_clauses=lo.shape[1], metric=metric, apply_pred=apply_pred,
-        int8=int8)
-    # predicate fields padded to the meta segment: extra lanes inactive
+        int8=rows.int8)
+    # predicate fields padded to the meta view: extra lanes inactive
     pad = ((0, 0), (0, 0), (0, 128 - m))
     lo3 = jnp.pad(lo.astype(jnp.float32), pad)
     hi3 = jnp.pad(hi.astype(jnp.float32), pad)
@@ -333,11 +386,13 @@ def gather_score_blocks(cand, vectors, qs, weights, scalars, lo, hi, active,
     def out_block(width):
         return pl.BlockSpec((1, 1, width), lambda b_, j: (b_ * nb + j, 0, 0))
 
+    views = tuple(rows.views) + (rows.meta,)
     out_s, out_i, out_q = pl.pallas_call(
         kern,
         grid=(b, nb),
         in_specs=[hbm, per_query(w3), per_query(lo3), per_query(hi3),
-                  per_query(act3), per_query(cval3), per_query(q3), hbm],
+                  per_query(act3), per_query(cval3), per_query(q3)]
+        + [hbm] * len(views),
         out_specs=[out_block(k_pad), out_block(k_pad), out_block(128)],
         out_shape=[
             jax.ShapeDtypeStruct((b * nb, 1, k_pad), jnp.float32),
@@ -347,11 +402,11 @@ def gather_score_blocks(cand, vectors, qs, weights, scalars, lo, hi, active,
         scratch_shapes=[
             pltpu.SMEM((1, ids3.shape[-1]), jnp.int32),  # block's cand ids
             pltpu.VMEM((block_s, 128), jnp.int32),  # the same ids as a column
-            pltpu.VMEM((block_s, 1, view.shape[-1]), view.dtype),  # rows
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        ] + [pltpu.VMEM((block_s, 1, v.shape[-1]), v.dtype)  # gathered rows
+             for v in views]
+        + [pltpu.SemaphoreType.DMA((1 + len(views),))],
         interpret=interpret,
-    )(ids3, w3, lo3, hi3, act3, cval3, q3, view)
+    )(ids3, w3, lo3, hi3, act3, cval3, q3, *views)
     return (out_s[:, 0, :k].reshape(b, nb, k),
             out_i[:, 0, :k].reshape(b, nb, k),
             out_q[:, 0, 0].reshape(b, nb))
@@ -365,24 +420,22 @@ def _default_use_kernel() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def gather_score_topk(cand, vectors, qs, weights, scalars, pred=None, *,
+def gather_score_topk(cand, rows: GatherRows, qs, weights, pred=None, *,
                       k: int, metric: str = "dot",
                       block_s: int = GATHER_BLOCK_S,
                       use_kernel: bool | None = None,
-                      interpret: bool | None = None,
-                      scales=None):
+                      interpret: bool | None = None):
     """Fused candidate-local filtered top-k for a query batch.
 
     cand:    (B, S) i32 candidate row ids, -1 = padded/empty slot (duplicates
              allowed — they are deduplicated before selection).
-    vectors: tuple of (n, d_i) table columns; qs: tuple of (B, d_i) queries;
-    weights: (B, n_vec) per-column weights; scalars: (n, M).
+    rows:    the scored columns and the scalars (``GatherRows``); int8 rows
+             run the quantized tier (4× fewer gathered HBM bytes; the DNF
+             mask still evaluates on the exact fp32 scalars).
+    qs:      tuple of (B, d_i) queries, one per column of ``rows``;
+    weights: (B, n_vec) per-column weights.
     pred:    batched PredicateLike (leading axis B) or None to skip masking
              (candidates already qualified, e.g. the rerank union).
-    scales:  tuple of (n,) f32 per-row dequant scales — when given,
-             ``vectors`` are the int8 replicas and scoring runs the
-             quantized tier (4× fewer gathered HBM bytes; the DNF mask
-             still evaluates on the exact fp32 scalars).
 
     -> (ids (B, k), scores (B, k), n_qualified (B,)). Empty slots carry
     id -1 / score NEG; ties break by smaller row id. Traceable — callers
@@ -394,7 +447,7 @@ def gather_score_topk(cand, vectors, qs, weights, scalars, pred=None, *,
     if apply_pred:
         lo, hi, act, cval = _pred_fields(pred)
     else:
-        m = scalars.shape[1]
+        m = rows.scalars.shape[1]
         lo = jnp.full((b, 1, m), -jnp.inf, jnp.float32)
         hi = jnp.full((b, 1, m), jnp.inf, jnp.float32)
         act = jnp.zeros((b, 1, m), jnp.float32)
@@ -403,9 +456,9 @@ def gather_score_topk(cand, vectors, qs, weights, scalars, pred=None, *,
     if not use_kernel:
         from repro.kernels.ref import gather_score_ref
 
-        return gather_score_ref(cand, vectors, qs, weights, scalars,
+        return gather_score_ref(cand, rows.vectors, qs, weights, rows.scalars,
                                 lo, hi, act, cval, k=k, metric=metric,
-                                apply_pred=apply_pred, scales=scales)
+                                apply_pred=apply_pred, scales=rows.scales)
 
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
@@ -415,12 +468,9 @@ def gather_score_topk(cand, vectors, qs, weights, scalars, pred=None, *,
         pad += ((k - (s_tot + pad)) + bs - 1) // bs * bs
     if pad:
         cand = jnp.pad(cand, ((0, 0), (0, pad)), constant_values=-1)
-    scales2 = None if scales is None else tuple(
-        s.astype(jnp.float32) for s in scales)
     out_s, out_i, out_q = gather_score_blocks(
-        cand, tuple(vectors), tuple(qs), weights, scalars, lo, hi, act, cval,
-        scales2, k=k, block_s=bs, metric=metric, apply_pred=apply_pred,
-        interpret=interpret)
+        cand, rows, tuple(qs), weights, lo, hi, act, cval, k=k, block_s=bs,
+        metric=metric, apply_pred=apply_pred, interpret=interpret)
     nb = cand.shape[1] // bs
     ids, scores = merge_topk_unique(
         out_i.reshape(b, nb * k), out_s.reshape(b, nb * k), k)
@@ -434,19 +484,19 @@ def gather_score_topk(cand, vectors, qs, weights, scalars, pred=None, *,
 RERANK_MULT = 4
 
 
-def gather_score_topk_int8(cand, vectors, vectors_i8, scales, qs, weights,
-                           scalars, pred=None, *, k: int,
+def gather_score_topk_int8(cand, rows: GatherRows, rows_i8: GatherRows, qs,
+                           weights, pred=None, *, k: int,
                            metric: str = "dot",
                            rerank_mult: int = RERANK_MULT,
                            block_s: int = GATHER_BLOCK_S,
                            use_kernel: bool | None = None,
                            interpret: bool | None = None):
     """Two-stage quantized candidate-local top-k: int8 gather→score→DNF-mask
-    keeps the top ``rerank_mult·k`` candidates (predicates evaluate on the
-    EXACT scalars, so filtering is bit-identical to fp32), then the fp32
-    kernel reranks exactly those rows — returned scores are exact fp32 and
-    the quantization can only affect which near-boundary rows reach the
-    rerank pool.
+    over ``rows_i8`` keeps the top ``rerank_mult·k`` candidates (predicates
+    evaluate on the EXACT scalars, so filtering is bit-identical to fp32),
+    then the fp32 kernel reranks exactly those rows of ``rows`` — returned
+    scores are exact fp32 and the quantization can only affect which
+    near-boundary rows reach the rerank pool.
 
     Same contract as ``gather_score_topk``; ``n_qualified`` counts the
     original candidate list's qualifying slots (stage-1 semantics)."""
@@ -454,12 +504,11 @@ def gather_score_topk_int8(cand, vectors, vectors_i8, scales, qs, weights,
 
     kq = max(k, min(rerank_mult * k, MAX_TOPK))
     ids_q, _, n_qual = gather_score_topk(
-        cand, vectors_i8, qs, weights, scalars, pred, k=kq, metric=metric,
-        block_s=block_s, use_kernel=use_kernel, interpret=interpret,
-        scales=scales)
+        cand, rows_i8, qs, weights, pred, k=kq, metric=metric,
+        block_s=block_s, use_kernel=use_kernel, interpret=interpret)
     # survivors are already predicate-qualified and deduplicated (-1 pads)
     ids, scores, _ = gather_score_topk(
-        ids_q, vectors, qs, weights, scalars, None, k=k, metric=metric,
+        ids_q, rows, qs, weights, None, k=k, metric=metric,
         block_s=block_s, use_kernel=use_kernel, interpret=interpret)
     return ids, scores, n_qual
 

@@ -88,10 +88,11 @@ def gather_tile_bytes(dims: tuple, n_scalars: int, n_clauses: int, *,
                       k: int = MAX_TOPK,
                       block_s: int = GATHER_BLOCK_S) -> int:
     """Resident bytes per grid step of ``gather_score_blocks``: the
-    (block_s, 1, W) scratch of gathered row-view rows (each column padded
-    to 128 lanes, then a 128-lane scalar segment), the (block_s, 128) id
-    column + pinned per-query operands and output rows. The scalars ride
-    in the meta segment, so ``n_scalars`` adds nothing while it fits."""
+    (block_s, 1, W_i) scratch of gathered rows of each column's row view
+    (padded to 128 lanes) and of the 128-lane scalars view, the
+    (block_s, 128) id column + pinned per-query operands and output rows.
+    The scalars ride in their 128-lane view, so ``n_scalars`` adds nothing
+    while it fits."""
     w = sum(round128(d) for d in dims) + 128
     scratch = block_s * (w + 128) * _F32
     pinned = (w + 3 * n_clauses * 128 + n_clauses) * _F32
@@ -130,8 +131,8 @@ def beam_tile_bytes(dim: int, n_scalars: int, n_clauses: int = 4, *,
 def int8_gather_tile_bytes(dims: tuple, n_scalars: int, n_clauses: int, *,
                            k: int = MAX_TOPK,
                            block_s: int = GATHER_BLOCK_S) -> int:
-    """``gather_tile_bytes`` for the quantized tier: each column's segment
-    is d/4 i32 words (four packed int8 lanes) padded to 128 lanes; the meta
-    segment carries the scalars and the dequant scales."""
-    return gather_tile_bytes(tuple(-(-d // 4) for d in dims), n_scalars,
+    """``gather_tile_bytes`` for the quantized tier: each column's row is
+    d/4 i32 words (four packed int8 lanes) and the row's dequant scale,
+    padded to 128 lanes."""
+    return gather_tile_bytes(tuple(-(-d // 4) + 1 for d in dims), n_scalars,
                              n_clauses, k=k, block_s=block_s)

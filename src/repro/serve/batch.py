@@ -61,7 +61,7 @@ from repro.vectordb.distributed import (
     build_sharded_ivf, sharded_batch_topk, sharded_ivf_topk, sharded_topk_ref,
 )
 from repro.vectordb.predicates import eval_mask
-from repro.vectordb.table import Table, exact_similarity
+from repro.vectordb.table import Table, similarity
 
 # Dense-score budget: each chunk holds (batch, n_rows) f32 score matrices
 # per active vector column; chunks are sized so batch · n_rows stays under
@@ -324,11 +324,9 @@ def warm_bucket_ladder(execute_batch, queries: list, batch_size: int) -> None:
 @partial(jax.jit, static_argnames=("metric",))
 def _dense_scores(vectors, q_b, *, metric):
     """(B, n) similarities of every row against every query in the batch —
-    ONE fused pass over the table instead of B (max_scan, d) vector
-    gathers, in f32 products (``exact_similarity``): these scores are
-    returned as results. All downstream kernels gather f32 scores, not
-    d-dim vectors."""
-    return jax.vmap(lambda q: exact_similarity(q, vectors, metric))(q_b)
+    ONE GEMM instead of B (max_scan, d) vector gathers. All downstream
+    kernels gather f32 scores, not d-dim vectors."""
+    return jax.vmap(lambda q: similarity(q, vectors, metric))(q_b)
 
 
 def compute_batch_scores(table: Table, queries: list[MHQ]) -> tuple:
@@ -390,11 +388,11 @@ def _selectivity_batch(hists, pred_b):
 
 
 @partial(jax.jit, static_argnames=("k", "metric"))
-def _gather_rerank_batch(rows_b, vectors, q_b, w_b, scalars, *, k, metric):
+def _gather_rerank_batch(rows_b, rows, q_b, w_b, *, k, metric):
     """Candidate-local weighted re-rank: fused gather+score+dedup+top-k over
     the candidate union — no (B, n) weighted score matrix."""
-    return gather_score_topk(rows_b, vectors, q_b, w_b, scalars, None,
-                             k=k, metric=metric)
+    return gather_score_topk(rows_b, rows, q_b, w_b, None, k=k,
+                             metric=metric)
 
 
 @partial(jax.jit, static_argnames=("size",))
@@ -422,7 +420,7 @@ def _sharded_exact_retry(vectors, scalars, pred_b, q_b, w_b, need_b, *,
     ws = jnp.zeros((w_b.shape[0], n), jnp.float32)
     for i, v in enumerate(vectors):
         ws = ws + w_b[:, i, None] * jax.vmap(
-            lambda q, vv=v: exact_similarity(q, vv, metric))(q_b[i])
+            lambda q, vv=v: similarity(q, vv, metric))(q_b[i])
     shard_of = jnp.minimum(jnp.arange(n, dtype=jnp.int32) // shard_len,
                            s_count - 1)
     allow = need_b[:, shard_of]
@@ -703,19 +701,19 @@ class BatchedHybridExecutor:
         if fkey not in self._sivf_fns:
             k, subs = key[2], key[3]
             shard_subs, total = [], 0
-            for (col, k_i, np0, ms, _it) in subs:
+            for (col, k_i, np0, ms, it) in subs:
                 sivf = self._sivf_col(col)
                 k_s, np_s, ms_s = legalize_for_shard(
                     k_i, np0, ms, n_shards=self.n_shards,
                     shard_len=sivf.shard_len, n_clusters=sivf.n_clusters)
                 ks = subquery_width(k_s, ms_s)
-                shard_subs.append((act.index(col), k_s, ks, np_s, ms_s))
+                shard_subs.append((act.index(col), k_s, ks, np_s, ms_s, it))
                 total += k_s
             pad_total = (rrf_union_total(total) if len(shard_subs) > 1
                          else next_bucket(total, CANDIDATE_PAD_FLOOR))
             self._sivf_fns[fkey] = sharded_ivf_topk(
                 self.n_shards, self.mesh, self.shard_axes,
-                subs=tuple(shard_subs), k=k, n_cols=len(act),
+                subs=tuple(shard_subs), k=k,
                 metric=self.table.schema.metric, pad_total=pad_total)
         return self._sivf_fns[fkey]
 
@@ -738,14 +736,14 @@ class BatchedHybridExecutor:
         # int8-precision group routed here keeps the exact scoring
         bb = min(next_bucket(len(qs)), bucket_cap)
         pred_b, qv_b, w_b = self._stack_inputs(qs, bb)
-        vecs, qsb, wsub, act = self._active_columns(qs, qv_b, w_b)
+        qsb, wsub, act = self._active_columns(qs, qv_b, w_b)
         sivfs = [self._sivf_col(col) for (col, *_r) in subs]
         fn = self._sivf_fn(key, act)
-        ids, scores, fill, bnd = fn(
+        ids, scores, fill, bnd, starved = fn(
             tuple(s.centroids for s in sivfs),
             tuple(s.sorted_rows for s in sivfs),
             tuple(s.offsets for s in sivfs),
-            vecs, t.scalars, pred_b, qsb, wsub)
+            t.gather_rows(act), pred_b, qsb, wsub)
         # fill/boundary and the merged ids ride along with the results in
         # one transfer — no mid-chunk host round-trip gates the kernels.
         # The finer trigger fixes "escalation never bites": the merged
@@ -769,13 +767,17 @@ class BatchedHybridExecutor:
             # the lone shard's local top-k IS the merge, so its boundary
             # always sits at the cutoff and carries no signal
             need |= ~under[:, None] & (bnd_np >= kth[:, None])
+            # a shard whose probe of an iterative subquery qualified fewer
+            # than k_i rows is where the single-device path re-expands
+            # nprobe (iterative_scan): it takes the exact retry instead
+            need |= np.asarray(starved)
         need[len(qs):] = False  # padding queries never escalate
         self.escalated.update(part[j] for j in np.flatnonzero(
             need.any(axis=1)))
         if need.any():
             ids, scores = self._escalate_shards(
-                ids, scores, need, k=k, pred_b=pred_b, vecs=vecs, qsb=qsb,
-                wsub=wsub)
+                ids, scores, need, k=k, pred_b=pred_b,
+                vecs=tuple(t.vectors[i] for i in act), qsb=qsb, wsub=wsub)
             ids_np = np.asarray(ids)
         else:
             ids_np = ids_np0  # already on host — don't transfer twice
@@ -878,11 +880,11 @@ class BatchedHybridExecutor:
                                           group=("sharded", k),
                                           prefer_dense=prefer_dense)
             if path == CANDIDATE_LOCAL:
-                vecs, qsb, wsub, _ = self._active_columns(qs, qv_b, w_b)
+                qsb, wsub, act = self._active_columns(qs, qv_b, w_b)
+                rows = t.gather_rows(act)
                 rows_b = _qualifying_rows_batch(mask, size=mc)
                 out_ids, out_scores, _ = _gather_rerank_batch(
-                    rows_b, vecs, qsb, wsub, t.scalars,
-                    k=k, metric=t.schema.metric)
+                    rows_b, rows, qsb, wsub, k=k, metric=t.schema.metric)
                 if estimated:
                     # true counts ride back with the result transfer
                     if n_qual is None:
@@ -891,7 +893,7 @@ class BatchedHybridExecutor:
                     if over.size:
                         out_ids, out_scores = self._regather_overflow(
                             mask, n_qual, over, out_ids, out_scores,
-                            vecs, qsb, wsub, k=k)
+                            rows, qsb, wsub, k=k)
             else:
                 _, weighted_scores = self._chunk_scores(
                     qs, part, bb, qv_b, w_b, scores_b)
@@ -902,7 +904,7 @@ class BatchedHybridExecutor:
             out[j] = (ids_np[pos], scores_np[pos])
 
     def _regather_overflow(self, mask, n_qual: np.ndarray, over: np.ndarray,
-                           out_ids, out_scores, vecs, qsb, wsub, *, k: int):
+                           out_ids, out_scores, rows, qsb, wsub, *, k: int):
         """Overflow escalation of the histogram-capped exact gather: the
         queries whose true qualifying count exceeded the static cap re-run
         at their exact width, so an under-shooting estimate can never drop
@@ -915,8 +917,8 @@ class BatchedHybridExecutor:
         rows2 = _qualifying_rows_batch(
             jnp.asarray(mask)[sel_j], size=mc2)
         ids2, sc2, _ = _gather_rerank_batch(
-            rows2, vecs, tuple(q[sel_j] for q in qsb), wsub[sel_j],
-            t.scalars, k=k, metric=t.schema.metric)
+            rows2, rows, tuple(q[sel_j] for q in qsb), wsub[sel_j],
+            k=k, metric=t.schema.metric)
         sel = jnp.asarray(over)
         out_ids = jnp.asarray(out_ids).at[sel].set(ids2[: len(over)])
         out_scores = jnp.asarray(out_scores).at[sel].set(sc2[: len(over)])
@@ -1014,7 +1016,7 @@ class BatchedHybridExecutor:
         if key[0] == "ff":
             _, _, k, mc = key
             out_ids, out_scores, _, _ = flat.filter_first_local_batch(
-                tuple(t.vectors), t.scalars, pred_b, qv_b, w_b, k=k,
+                t.gather_rows(), pred_b, qv_b, w_b, k=k,
                 max_candidates=mc, n_vec=t.schema.n_vec,
                 metric=t.schema.metric)
             return out_ids, out_scores
@@ -1028,24 +1030,23 @@ class BatchedHybridExecutor:
                                            precision=precision)
                     for (col, k_i, np0, ms, it) in subs]
         rows_b = self._union_candidates(cand, subs)
-        vecs, qsb, wsub, _ = self._active_columns(qs, qv_b, w_b)
+        qsb, wsub, act = self._active_columns(qs, qv_b, w_b)
         out_ids, out_scores, _ = _gather_rerank_batch(
-            rows_b.astype(jnp.int32), vecs, qsb, wsub, t.scalars,
+            rows_b.astype(jnp.int32), t.gather_rows(act), qsb, wsub,
             k=k, metric=t.schema.metric)
         return out_ids, out_scores
 
     def _active_columns(self, qs: list[MHQ], qv_b: tuple, w_b):
-        """Restrict (vectors, queries, weights) to columns some query in the
-        chunk actually weights — a zero weight contributes exactly 0, so the
+        """Restrict (queries, weights) to columns some query in the chunk
+        actually weights — a zero weight contributes exactly 0, so the
         candidate-local re-rank need not gather those columns at all.
-        Returns (vectors, queries, weights, active column ids)."""
+        Returns (queries, weights, active column ids)."""
         w_np = np.asarray([q.weights for q in qs], np.float32)
         act = tuple(i for i in range(self.table.schema.n_vec)
                     if np.any(np.abs(w_np[:, i]) > 0))
-        vecs = tuple(self.table.vectors[i] for i in act)
         qsb = tuple(qv_b[i] for i in act)
         wsub = w_b[:, jnp.asarray(act, jnp.int32)] if act else w_b[:, :0]
-        return vecs, qsb, wsub, act
+        return qsb, wsub, act
 
     @staticmethod
     def _pad_candidates(cand: list):
@@ -1091,7 +1092,7 @@ class BatchedHybridExecutor:
         t = self.table
         ks = subquery_width(k_i, t.n_rows)
         ids, _, _, _ = graph.search_local_batch(
-            self.graphs[col], t.vectors[col], t.scalars, pred_b, q_b,
+            self.graphs[col], t.gather_rows((col,)), pred_b, q_b,
             beam_width=beam_width, n_hops=n_hops, k=ks)
         return ids
 
@@ -1119,13 +1120,13 @@ class BatchedHybridExecutor:
 
         def probe(np_, pred, qb, rs):
             if local and precision == "int8":
-                vq, sc = t.quantized(col)
                 ids_, _, _, nq = ivf.search_local_batch_int8(
-                    index, t.vectors[col], vq, sc, t.scalars, pred, qb,
+                    index, t.gather_rows((col,)),
+                    t.gather_rows((col,), int8=True), pred, qb,
                     nprobe=np_, max_scan=max_scan, k=ks)
             elif local:
                 ids_, _, _, nq = ivf.search_local_batch(
-                    index, t.vectors[col], t.scalars, pred, qb,
+                    index, t.gather_rows((col,)), pred, qb,
                     nprobe=np_, max_scan=max_scan, k=ks)
             else:
                 ids_, _, _, nq = _search_batch(

@@ -48,21 +48,13 @@ def place_rows(x: jax.Array, mesh: Mesh, data_axes=("data",)) -> jax.Array:
 
 
 def place_table(table, mesh: Mesh, data_axes=("data",)):
-    """A copy of ``table`` whose columns, scalars and int8 replicas (built
-    here if missing) are row-sharded over the mesh by ``place_rows``:
-    contiguous row blocks, block i on device i, as ``shard_map`` slices
-    them."""
-    from repro.vectordb.table import Table
-
-    for i in range(table.schema.n_vec):
-        table.quantized(i)
-    return Table(
-        schema=table.schema,
-        vectors=[place_rows(v, mesh, data_axes) for v in table.vectors],
-        scalars=place_rows(table.scalars, mesh, data_axes),
-        vectors_i8=[place_rows(v, mesh, data_axes)
-                    for v in table.vectors_i8],
-        scales=[place_rows(s, mesh, data_axes) for s in table.scales])
+    """A copy of ``table`` whose columns, scalars, int8 replicas and gather
+    row views (built here if missing) are row-sharded over the mesh by
+    ``place_rows``: contiguous row blocks, block i on device i, as
+    ``shard_map`` slices them."""
+    table.gather_rows()
+    table.gather_rows(int8=True)
+    return table.with_arrays(lambda x: place_rows(x, mesh, data_axes))
 
 
 def sharded_masked_scan(mesh: Mesh, data_axes=("data",), *, k: int, n_vec: int,
@@ -387,15 +379,16 @@ def build_sharded_ivf(vectors: jax.Array, n_shards: int, *,
 
 def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
                      data_axes=("data",), *, subs: tuple, k: int,
-                     n_cols: int, metric: str, pad_total: int):
+                     metric: str, pad_total: int):
     """Build the jit'd plan-driven per-shard probing search.
 
     ``subs``: one entry per probed column, carrying the SHARD-LEGALIZED
-    static plan params ``(pos, k_i, ks, nprobe, max_scan)`` — ``pos``
-    indexes the column tuples passed at call time (the chunk's weighted
-    columns), ``ks`` the bucketed local top-k width, and
+    static plan params ``(pos, k_i, ks, nprobe, max_scan, iterative)`` —
+    ``pos`` indexes the column tuples passed at call time (the chunk's
+    weighted columns), ``ks`` the bucketed local top-k width,
     ``nprobe``/``max_scan`` the per-shard probing budget
-    (``executor.legalize_for_shard``). Each shard probes its own IVF index
+    (``executor.legalize_for_shard``) and ``iterative`` the plan's
+    re-expansion flag. Each shard probes its own IVF index
     (``ivf.search_local_batch``), reranks the per-shard candidate union by
     the full weighted score with the fused candidate-local gather+score
     kernel — the PR 4 path, now running INSIDE each shard — and keeps a
@@ -403,9 +396,10 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
 
     Returned fn signature:
       fn(cent_t, rows_t, offs_t  — per-probed-column ``ShardedIVF`` arrays,
-         vectors tuple[(n, d_i)], scalars (n, M), pred_b (stacked over B),
-         qv_t tuple[(B, d_i)], w_b (B, n_cols))
-        -> (ids (B, k), scores (B, k), fill (B, S), boundary (B, S))
+         rows (``GatherRows`` of the chunk's columns), pred_b (stacked
+         over B), qv_t tuple[(B, d_i)], w_b (B, n_cols))
+        -> (ids (B, k), scores (B, k), fill (B, S), boundary (B, S),
+            starved (B, S))
 
     ``fill[:, s]`` is how many candidates shard ``s`` contributed per query
     and ``boundary[:, s]`` is shard ``s``'s weakest VALID kept local score
@@ -415,11 +409,15 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
     above the global cutoff, so its probing budget (local truncation or a
     starved probe), not the data, was the binding constraint and rows it
     never surfaced may belong in the global top-k — the loss mode the old
-    merged-underfill trigger could never see. Without a mesh the shard
-    axis is looped on one device (a non-divisible table is zero-padded;
-    padded rows are unreachable by construction); with a mesh the identical
-    body runs under ``shard_map`` and the merge is one all-gather, in the
-    same shard order.
+    merged-underfill trigger could never see. ``starved[:, s]`` marks a
+    shard whose probe of an ``iterative`` subquery qualified fewer than
+    its k_i rows without probing every cluster: where the single-device
+    path re-expands nprobe (iterative_scan), the shard needs the same
+    escalation. Without a mesh the shard axis is looped on one device over
+    the whole table's rows (a short last shard's padded index slots are
+    unreachable by construction); with a mesh the identical body runs
+    under ``shard_map`` on each device's own row block and the merge is
+    one all-gather, in the same shard order.
     """
     from repro.core.executor import rrf_extras
     from repro.kernels.gather_score import gather_score_topk
@@ -428,15 +426,22 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
     s = max(1, int(n_shards))
     axes = data_axes if isinstance(data_axes, tuple) else (data_axes,)
 
-    def body(cent_t, rows_t, offs_t, vecs_t, scal, row0, pred_b, qv_t, w_b):
+    def body(cent_t, rows_t, offs_t, base, shift, rows, pred_b, qv_t, w_b):
         """One shard: probe each planned column, rerank the union, local
-        top-k. All ids are shard-local until the final globalization."""
+        top-k. Candidate ids are shard-local plus ``base``, the shard's
+        first row in ``rows`` (0 under shard_map, where ``rows`` is the
+        device's own block; the shard's offset on one device, where it is
+        the whole table); ``shift`` makes them global."""
         wide, cands = [], []
-        for j, (pos, k_i, ks, np_s, ms_s) in enumerate(subs):
-            idx = _ivf.IVFIndex(cent_t[j], rows_t[j], offs_t[j], metric)
-            ids_j, _, _, _ = _ivf.search_local_batch(
-                idx, vecs_t[pos], scal, pred_b, qv_t[pos],
+        starved = jnp.zeros((w_b.shape[0],), bool)
+        for j, (pos, k_i, ks, np_s, ms_s, it) in enumerate(subs):
+            idx = _ivf.IVFIndex(cent_t[j], rows_t[j] + base, offs_t[j],
+                                metric)
+            ids_j, _, _, nq_j = _ivf.search_local_batch(
+                idx, rows.select((pos,)), pred_b, qv_t[pos],
                 nprobe=np_s, max_scan=ms_s, k=ks)
+            if it and np_s < idx.n_clusters:
+                starved = starved | (nq_j < k_i)
             wide.append(ids_j)
             cands.append(ids_j[:, :k_i])
         rows_b = jnp.concatenate(cands, axis=1)
@@ -455,10 +460,10 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
                              ((0, 0), (0, pad_total - rows_b.shape[1])),
                              constant_values=-1)
         ids_l, scores_l, _ = gather_score_topk(
-            rows_b.astype(jnp.int32), vecs_t, qv_t, w_b, scal, None,
-            k=k, metric=metric)
+            rows_b.astype(jnp.int32), rows, qv_t, w_b, None, k=k,
+            metric=metric)
         fill = jnp.sum(ids_l >= 0, axis=1).astype(jnp.int32)
-        ids_g = jnp.where(ids_l >= 0, ids_l + row0, -1)
+        ids_g = jnp.where(ids_l >= 0, ids_l + shift, -1)
         # weakest VALID kept local score (scores are sorted descending, so
         # that is slot fill-1, the k-th when the shard kept a full top-k):
         # the boundary the escalation trigger compares against the merged
@@ -467,73 +472,67 @@ def sharded_ivf_topk(n_shards: int, mesh: Mesh | None = None,
         boundary = jnp.where(
             fill > 0, jnp.take_along_axis(scores_l, last, axis=1)[:, 0],
             jnp.float32(NEG))
-        return ids_g, scores_l, fill, boundary
+        return ids_g, scores_l, fill, boundary, starved
 
+    zero = jnp.int32(0)
     if mesh is None:
-        def run(cent_t, rows_t, offs_t, vectors, scalars, pred_b, qv_t, w_b):
-            n = scalars.shape[0]
+        def run(cent_t, rows_t, offs_t, rows, pred_b, qv_t, w_b):
+            n = rows.scalars.shape[0]
             shard_len = -(-n // s)
             if s == 1:
                 # degenerate configuration: EXACTLY the single-device
-                # candidate-local chunk (no vmap, no pad, identity merge)
-                ids, sc, fill, bnd = body(
+                # candidate-local chunk (no loop, identity merge)
+                ids, sc, fill, bnd, stv = body(
                     tuple(c[0] for c in cent_t), tuple(r[0] for r in rows_t),
-                    tuple(o[0] for o in offs_t), vectors, scalars,
-                    jnp.asarray(0, jnp.int32), pred_b, qv_t, w_b)
-                return ids, sc, fill[:, None], bnd[:, None]
-            pad = s * shard_len - n
-            if pad:
-                vectors = tuple(jnp.pad(v, ((0, pad), (0, 0)))
-                                for v in vectors)
-                scalars = jnp.pad(scalars, ((0, pad), (0, 0)))
-            vecs_sh = tuple(v.reshape(s, shard_len, v.shape[1])
-                            for v in vectors)
-            scal_sh = scalars.reshape(s, shard_len, scalars.shape[1])
+                    tuple(o[0] for o in offs_t), zero, zero, rows,
+                    pred_b, qv_t, w_b)
+                return ids, sc, fill[:, None], bnd[:, None], stv[:, None]
             row0 = jnp.arange(s, dtype=jnp.int32) * shard_len
             # a loop over the shard axis, not vmap: the gather kernel reads
             # its table from HBM (memory space ANY), which Mosaic cannot
-            # batch
-            ids, sc, fill, bnd = jax.lax.map(
-                lambda xs: body(*xs, pred_b, qv_t, w_b),
-                (cent_t, rows_t, offs_t, vecs_sh, scal_sh, row0))
+            # batch. Every shard reads the same whole-table rows, offset
+            # by its first row, so no shard's rows are sliced or copied.
+            ids, sc, fill, bnd, stv = jax.lax.map(
+                lambda xs: body(*xs, zero, rows, pred_b, qv_t, w_b),
+                (cent_t, rows_t, offs_t, row0))
             b = sc.shape[1]
             # (S, B, k) -> (B, S·k) in shard order — the all_gather layout
             s_all = jnp.swapaxes(sc, 0, 1).reshape(b, s * k)
             g_all = jnp.swapaxes(ids, 0, 1).reshape(b, s * k)
             mi, ms = _merge_shard_candidates(s_all, g_all, k=k)
-            return mi, ms, jnp.swapaxes(fill, 0, 1), jnp.swapaxes(bnd, 0, 1)
+            return (mi, ms, jnp.swapaxes(fill, 0, 1),
+                    jnp.swapaxes(bnd, 0, 1), jnp.swapaxes(stv, 0, 1))
 
         return jax.jit(run)
 
     sub_specs3 = tuple(P(axes, None, None) for _ in subs)
     sub_specs2 = tuple(P(axes, None) for _ in subs)
-    vec_specs = tuple(P(axes, None) for _ in range(n_cols))
 
-    def local(cent_t, rows_t, offs_t, vectors, scalars, pred_b, qv_t, w_b,
-              row0):
-        ids_g, sc, fill, bnd = body(
+    def local(cent_t, rows_t, offs_t, rows, pred_b, qv_t, w_b, row0):
+        ids_g, sc, fill, bnd, stv = body(
             tuple(c[0] for c in cent_t), tuple(r[0] for r in rows_t),
-            tuple(o[0] for o in offs_t), vectors, scalars, row0[0],
+            tuple(o[0] for o in offs_t), zero, row0[0], rows,
             pred_b, qv_t, w_b)
         s_all = jax.lax.all_gather(sc, axes, axis=1, tiled=True)
         g_all = jax.lax.all_gather(ids_g, axes, axis=1, tiled=True)
         mi, ms = _merge_shard_candidates(s_all, g_all, k=k)
-        return mi, ms, fill[None, :], bnd[None, :]
+        return mi, ms, fill[None, :], bnd[None, :], stv[None, :]
 
+    # every array of ``rows`` splits on its leading (row) axis
     fn = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(sub_specs3, sub_specs2, sub_specs2, vec_specs,
-                  P(axes, None), P(), tuple(P() for _ in range(n_cols)),
+        in_specs=(sub_specs3, sub_specs2, sub_specs2, P(axes), P(), P(),
                   P(), P(axes)),
-        out_specs=(P(), P(), P(axes, None), P(axes, None)),
+        out_specs=(P(), P(), P(axes, None), P(axes, None), P(axes, None)),
         check_vma=False)
 
-    def run(cent_t, rows_t, offs_t, vectors, scalars, pred_b, qv_t, w_b):
-        n = scalars.shape[0]
+    def run(cent_t, rows_t, offs_t, rows, pred_b, qv_t, w_b):
+        n = rows.scalars.shape[0]
         assert n % s == 0, (n, s)
         row0 = jnp.arange(s, dtype=jnp.int32) * (n // s)
-        mi, ms, fill, bnd = fn(cent_t, rows_t, offs_t, vectors, scalars,
-                               pred_b, qv_t, w_b, row0)
-        return mi, ms, jnp.swapaxes(fill, 0, 1), jnp.swapaxes(bnd, 0, 1)
+        mi, ms, fill, bnd, stv = fn(cent_t, rows_t, offs_t, rows, pred_b,
+                                    qv_t, w_b, row0)
+        return (mi, ms, jnp.swapaxes(fill, 0, 1), jnp.swapaxes(bnd, 0, 1),
+                jnp.swapaxes(stv, 0, 1))
 
     return jax.jit(run)

@@ -80,8 +80,7 @@ def filter_first_scored(
 @partial(jax.jit, static_argnames=("k", "max_candidates", "n_vec", "metric",
                                    "use_kernel", "interpret", "block_s"))
 def filter_first_local_batch(
-    vectors: tuple,  # tuple of (n, d_i)
-    scalars: jax.Array,
+    rows,  # GatherRows of the table's columns
     pred_b: PredicateLike,  # stacked, leading axis B
     query_vectors_b: tuple,  # tuple of (B, d_i)
     weights_b: jax.Array,  # (B, n_vec)
@@ -102,14 +101,14 @@ def filter_first_local_batch(
     the fused kernel skips re-masking."""
     from repro.kernels.gather_score import gather_score_topk
 
-    mask_b = jax.vmap(lambda p: eval_mask(p, scalars))(pred_b)  # (B, n)
+    mask_b = jax.vmap(lambda p: eval_mask(p, rows.scalars))(pred_b)  # (B, n)
     rows_b = jax.vmap(
         lambda m: jnp.nonzero(m, size=max_candidates, fill_value=-1)[0]
     )(mask_b)
     cand = rows_b.astype(jnp.int32)
     ids, scores, _ = gather_score_topk(
-        cand, tuple(vectors[:n_vec]), tuple(query_vectors_b[:n_vec]),
-        weights_b, scalars, None, k=k, metric=metric, use_kernel=use_kernel,
+        cand, rows.select(range(n_vec)), tuple(query_vectors_b[:n_vec]),
+        weights_b, None, k=k, metric=metric, use_kernel=use_kernel,
         interpret=interpret, block_s=block_s)
     return ids, scores, jnp.sum(cand >= 0, axis=1), jnp.sum(mask_b, axis=1)
 

@@ -337,8 +337,7 @@ def extend(index: GraphIndex, vectors: jax.Array,
 
 def search_local_batch(
     index: GraphIndex,
-    vectors: jax.Array,  # (n, d) the indexed column
-    scalars: jax.Array,  # (n, M)
+    rows,  # GatherRows of the indexed column
     pred_b: PredicateLike,  # stacked, leading axis B
     q_b: jax.Array,  # (B, d)
     *,
@@ -355,15 +354,14 @@ def search_local_batch(
     from repro.kernels.beam_search import beam_search_topk
 
     return beam_search_topk(
-        index.neighbors, index.entry_points, vectors, scalars, pred_b, q_b,
+        index.neighbors, index.entry_points, rows, pred_b, q_b,
         k=k, beam_width=beam_width, n_hops=n_hops, metric=index.metric,
         use_kernel=use_kernel, interpret=interpret)
 
 
 def search(
     index: GraphIndex,
-    vectors: jax.Array,
-    scalars: jax.Array,
+    rows,  # GatherRows of the indexed column
     pred: PredicateLike,
     q: jax.Array,  # (d,)
     *,
@@ -374,6 +372,6 @@ def search(
     """Single-query convenience wrapper mirroring ``ivf.search``:
     (ids (k,), scores (k,), n_scored (), n_qualified ())."""
     ids, scores, n_scored, n_qual = search_local_batch(
-        index, vectors, scalars, stack([pred]), q[None], k=k,
+        index, rows, stack([pred]), q[None], k=k,
         beam_width=beam_width, n_hops=n_hops)
     return ids[0], scores[0], n_scored[0], n_qual[0]

@@ -332,8 +332,7 @@ def preprobe_scored(
                                    "interpret", "block_s"))
 def search_local_batch(
     index: IVFIndex,
-    vectors: jax.Array,  # (n, d) the indexed column
-    scalars: jax.Array,  # (n, M)
+    rows,  # GatherRows of the indexed column
     pred_b: PredicateLike,  # stacked, leading axis B
     q_b: jax.Array,  # (B, d)
     *,
@@ -359,7 +358,7 @@ def search_local_batch(
     cand = jnp.where(valid_b, rows_b, -1).astype(jnp.int32)
     w = jnp.ones((q_b.shape[0], 1), jnp.float32)
     ids, scores, n_qual = gather_score_topk(
-        cand, (vectors,), (q_b,), w, scalars, pred_b, k=k,
+        cand, rows, (q_b,), w, pred_b, k=k,
         metric=index.metric, use_kernel=use_kernel, interpret=interpret,
         block_s=block_s)
     return ids, scores, jnp.sum(valid_b, axis=1), n_qual
@@ -369,10 +368,9 @@ def search_local_batch(
                                    "use_kernel", "interpret", "block_s"))
 def search_local_batch_int8(
     index: IVFIndex,
-    vectors: jax.Array,  # (n, d) exact fp32 column (the rerank tier)
-    vectors_i8: jax.Array,  # (n, d) int8 replica (the scoring tier)
-    scales: jax.Array,  # (n,) f32 per-row dequant scales
-    scalars: jax.Array,  # (n, M) — exact fp32, shared by both tiers
+    rows,  # GatherRows of the exact fp32 column (the rerank tier)
+    rows_i8,  # GatherRows of its int8 replica (the scoring tier); both
+    # carry the exact fp32 scalars
     pred_b: PredicateLike,  # stacked, leading axis B
     q_b: jax.Array,  # (B, d)
     *,
@@ -400,7 +398,7 @@ def search_local_batch_int8(
     w = jnp.ones((q_b.shape[0], 1), jnp.float32)
     kwargs = {} if rerank_mult is None else {"rerank_mult": rerank_mult}
     ids, scores, n_qual = gather_score_topk_int8(
-        cand, (vectors,), (vectors_i8,), (scales,), (q_b,), w, scalars,
-        pred_b, k=k, metric=index.metric, use_kernel=use_kernel,
-        interpret=interpret, block_s=block_s, **kwargs)
+        cand, rows, rows_i8, (q_b,), w, pred_b, k=k, metric=index.metric,
+        use_kernel=use_kernel, interpret=interpret, block_s=block_s,
+        **kwargs)
     return ids, scores, jnp.sum(valid_b, axis=1), n_qual

@@ -59,6 +59,10 @@ class Table:
     # maintained through append, so TieredTable compaction inherits it.
     vectors_i8: Optional[list] = None
     scales: Optional[list] = None
+    # the gather kernel's row views (``gather_rows``), built on first use
+    # per column and precision and kept with this table version
+    _views: dict = dataclasses.field(default_factory=dict, repr=False,
+                                     compare=False)
 
     @property
     def n_rows(self) -> int:
@@ -75,6 +79,43 @@ class Table:
 
             self.vectors_i8[i], self.scales[i] = quantize_rows(self.vectors[i])
         return self.vectors_i8[i], self.scales[i]
+
+    def gather_rows(self, cols=None, *, int8: bool = False):
+        """Columns ``cols`` (default: all) as the candidate-local scorers
+        read them (``kernels.gather_score.GatherRows``): fp32, or with
+        ``int8`` the int8 replicas. The row views cost one copy of each
+        column; they are built on first use and cached with this table
+        version, like the replica, so a call gathers only its candidates'
+        rows."""
+        from repro.kernels import gather_score as gs
+
+        cols = tuple(range(self.schema.n_vec)) if cols is None else tuple(cols)
+        if "meta" not in self._views:
+            self._views["meta"] = gs.meta_view(self.scalars)
+        for i in cols:
+            if (i, int8) not in self._views:
+                self._views[(i, int8)] = gs.int8_view(*self.quantized(i)) \
+                    if int8 else gs.column_view(self.vectors[i])
+        views = tuple(self._views[(i, int8)] for i in cols)
+        if int8:
+            return gs.GatherRows(
+                tuple(self.vectors_i8[i] for i in cols), self.scalars,
+                tuple(self.scales[i] for i in cols), views,
+                self._views["meta"])
+        return gs.GatherRows(tuple(self.vectors[i] for i in cols),
+                             self.scalars, None, views, self._views["meta"])
+
+    def with_arrays(self, fn) -> "Table":
+        """This table version with ``fn`` applied to every array it holds —
+        columns, scalars, int8 replicas and built row views (placement)."""
+        def each(xs):
+            return None if xs is None else [
+                None if x is None else fn(x) for x in xs]
+
+        new = Table(self.schema, each(self.vectors), fn(self.scalars),
+                    each(self.vectors_i8), each(self.scales))
+        new._views.update((key, fn(v)) for key, v in self._views.items())
+        return new
 
     @staticmethod
     def from_numpy(schema: TableSchema, vectors: list[np.ndarray], scalars: np.ndarray) -> "Table":
@@ -116,26 +157,17 @@ class Table:
 
 
 def similarity(q: jax.Array, vecs: jax.Array, metric: str) -> jax.Array:
-    """Score rows of ``vecs`` (n, d) against ``q`` (d,). Higher = better."""
-    if metric == "dot":
-        return vecs @ q
-    if metric == "l2":
-        # -||v - q||^2 expanded — keeps it a single matmul + row norms
-        return 2.0 * (vecs @ q) - jnp.sum(vecs * vecs, axis=-1) - jnp.sum(q * q)
-    raise ValueError(f"unknown metric {metric!r}")
+    """Score rows of ``vecs`` (n, d) against ``q`` (d,). Higher = better.
 
-
-def exact_similarity(q: jax.Array, vecs: jax.Array, metric: str) -> jax.Array:
-    """``similarity`` with f32 products summed on the vector units, for the
-    batched dense path, whose scores are returned as results. On a TPU an
-    f32 matmul runs reduced-precision bf16 passes by default (score errors
-    near 3e-4 on these unit-norm 768-d rows, against the oracle's 1e-4
-    tolerance), and a HIGHEST-precision matmul crashed the TPU compiler on
-    the chip."""
-    dot = jnp.sum(vecs * q, axis=-1)
+    Scores are returned as results, so the matmul runs at HIGHEST
+    precision: on a TPU an f32 matmul otherwise takes one reduced-precision
+    bf16 pass, which missed the oracle's 1e-4 tolerance on part's unit-norm
+    768-d rows (2.6e-4 off on a TPU v5e)."""
+    dot = jnp.matmul(vecs, q, precision=jax.lax.Precision.HIGHEST)
     if metric == "dot":
         return dot
     if metric == "l2":
+        # -||v - q||^2 expanded — keeps it a single matmul + row norms
         return 2.0 * dot - jnp.sum(vecs * vecs, axis=-1) - jnp.sum(q * q)
     raise ValueError(f"unknown metric {metric!r}")
 

@@ -90,9 +90,10 @@ class HotView:
 
     Construction is a host-side token: it captures REFERENCES to the
     generation's full-capacity host buffers plus ``count``/``id_offset``.
-    The device copies (static shapes keep the jit cache bounded) are built
-    LAZILY — on the first ``vectors``/``scalars`` read, i.e. the first query
-    snapshot that actually scores this view — and cached per view, so
+    The device copies (static shapes keep the jit cache bounded), with the
+    gather kernel's row views (``rows``), are built LAZILY — on the first
+    ``rows``/``vectors``/``scalars`` read, i.e. the first query snapshot
+    that actually scores this view — and cached per view, so
     insert-heavy windows with no interleaved reads publish versions at zero
     transfer cost (``hot_view_transfers`` counts the copies).
 
@@ -113,13 +114,18 @@ class HotView:
         self._device = None
         self._lock = threading.Lock()
 
-    def _materialize(self):
+    @property
+    def rows(self):
+        """The view's device copy as ``GatherRows``."""
         if self._device is None:
             with self._lock:
                 if self._device is None:
+                    from repro.kernels.gather_score import GatherRows
+
                     global _hot_view_transfers
-                    dev = (tuple(jnp.asarray(b) for b in self.np_vectors),
-                           jnp.asarray(self.np_scalars))
+                    dev = GatherRows.build(
+                        tuple(jnp.asarray(b) for b in self.np_vectors),
+                        jnp.asarray(self.np_scalars))
                     with _transfer_lock:
                         _hot_view_transfers += len(self.np_vectors) + 1
                     self._device = dev
@@ -127,11 +133,11 @@ class HotView:
 
     @property
     def vectors(self) -> tuple:
-        return self._materialize()[0]
+        return self.rows.vectors
 
     @property
     def scalars(self) -> jax.Array:
-        return self._materialize()[1]
+        return self.rows.scalars
 
     @property
     def capacity(self) -> int:
@@ -443,14 +449,14 @@ def _hot_topk(view_args, qs, weights, pred_b, *, k: int, metric: str):
     view's offset."""
     from repro.kernels.gather_score import gather_score_topk
 
-    vectors, scalars, count, id_offset = view_args
-    cap = scalars.shape[0]
+    rows, count, id_offset = view_args
+    cap = rows.scalars.shape[0]
     b = weights.shape[0]
     slots = jnp.arange(cap, dtype=jnp.int32)
     cand = jnp.where(slots[None, :] < count, slots[None, :], -1)
     cand = jnp.broadcast_to(cand, (b, cap)).astype(jnp.int32)
     ids, scores, n_qual = gather_score_topk(
-        cand, vectors, qs, weights, scalars, pred_b, k=k, metric=metric)
+        cand, rows, qs, weights, pred_b, k=k, metric=metric)
     ids = jnp.where(ids >= 0, ids + id_offset, -1).astype(jnp.int32)
     return ids, scores, n_qual
 
@@ -464,7 +470,7 @@ def merge_hot_batch(cold_ids, cold_scores, views, qs, weights, pred_b, *,
     spaces are disjoint by construction, so dedup is a no-op and ties break
     by smaller global id exactly like the sharded merge.
 
-    ``views``: tuple of (vectors, scalars, count, id_offset) pytrees —
+    ``views``: tuple of (rows, count, id_offset) pytrees —
     count/id_offset ride as traced scalars so inserts never recompile;
     only the view COUNT (1 vs 2, during compaction) and the static shapes
     key the jit cache."""
@@ -482,6 +488,5 @@ def merge_hot_batch(cold_ids, cold_scores, views, qs, weights, pred_b, *,
 
 def view_args(view: HotView):
     """HotView -> the traced pytree ``merge_hot_batch`` consumes."""
-    return (view.vectors, view.scalars,
-            jnp.asarray(view.count, jnp.int32),
+    return (view.rows, jnp.asarray(view.count, jnp.int32),
             jnp.asarray(view.id_offset, jnp.int32))

@@ -135,24 +135,27 @@ _SUBPROC = textwrap.dedent("""
     # --- per-shard IVF probing: shard_map == logical reference ---
     from repro.vectordb.distributed import build_sharded_ivf, sharded_ivf_topk
     sivf = build_sharded_ivf(vecs[0], 4, n_clusters=8, seed=3, metric="dot")
-    subs = ((0, 16, 16, 4, 64),)  # (pos, k_i, ks, nprobe, max_scan)
+    # (pos, k_i, ks, nprobe, max_scan, iterative)
+    subs = ((0, 16, 16, 4, 64, True),)
     qv_b = jnp.asarray(rng.normal(size=(qb, d)), jnp.float32)
     w_b = jnp.ones((qb, 1), jnp.float32)
+    from repro.kernels.gather_score import GatherRows
     args = ((sivf.centroids,), (sivf.sorted_rows,), (sivf.offsets,),
-            (vecs[0],), scal, preds, (qv_b,), w_b)
-    fn_m = sharded_ivf_topk(4, mesh, ("data",), subs=subs, k=k2, n_cols=1,
+            GatherRows.build((vecs[0],), scal), preds, (qv_b,), w_b)
+    fn_m = sharded_ivf_topk(4, mesh, ("data",), subs=subs, k=k2,
                             metric="dot", pad_total=64)
-    fn_r = sharded_ivf_topk(4, None, subs=subs, k=k2, n_cols=1,
+    fn_r = sharded_ivf_topk(4, None, subs=subs, k=k2,
                             metric="dot", pad_total=64)
     with mesh:
-        ids_m, s_m, fill_m, bnd_m = fn_m(*args)
-    ids_l, s_l, fill_l, bnd_l = fn_r(*args)
+        ids_m, s_m, fill_m, bnd_m, stv_m = fn_m(*args)
+    ids_l, s_l, fill_l, bnd_l, stv_l = fn_r(*args)
     assert np.array_equal(np.asarray(ids_m), np.asarray(ids_l)), (ids_m, ids_l)
     assert np.allclose(np.asarray(s_m), np.asarray(s_l), atol=1e-5)
     assert np.array_equal(np.asarray(fill_m), np.asarray(fill_l))
     assert np.asarray(fill_m).shape == (qb, 4)
     assert np.allclose(np.asarray(bnd_m), np.asarray(bnd_l), atol=1e-5)
     assert np.asarray(bnd_m).shape == (qb, 4)
+    assert np.array_equal(np.asarray(stv_m), np.asarray(stv_l))
     print("sharded_ivf OK")
 
     # --- elastic replan onto a reshaped mesh ---

@@ -26,7 +26,7 @@ import jax.numpy as jnp
 from oracle import eval_mask_np, similarity_np, tie_tolerance
 
 from repro.kernels.gather_score import (
-    NEG, gather_score_topk, merge_topk_unique,
+    NEG, GatherRows, gather_score_topk, merge_topk_unique,
 )
 from repro.vectordb.predicates import PredicateSet, Predicates, stack
 
@@ -120,12 +120,12 @@ def _assert_vs_oracle(ids, scores, o_ids, o_scores, *, atol=1e-3):
 
 def _run_all_paths(cand, vectors, qs, weights, scalars, pred_b, *, k, metric,
                    block_s=32):
-    kern = gather_score_topk(jnp.asarray(cand), vectors, qs, weights,
-                             scalars, pred_b, k=k, metric=metric,
-                             use_kernel=True, interpret=True, block_s=block_s)
-    ref = gather_score_topk(jnp.asarray(cand), vectors, qs, weights,
-                            scalars, pred_b, k=k, metric=metric,
-                            use_kernel=False)
+    rows = GatherRows.build(vectors, scalars)
+    kern = gather_score_topk(jnp.asarray(cand), rows, qs, weights, pred_b,
+                             k=k, metric=metric, use_kernel=True,
+                             interpret=True, block_s=block_s)
+    ref = gather_score_topk(jnp.asarray(cand), rows, qs, weights, pred_b,
+                            k=k, metric=metric, use_kernel=False)
     return kern, ref
 
 
@@ -182,6 +182,39 @@ def test_kernel_parity_corpus(seed, n, dims, m, b, s, c, k, metric):
                 c=c, k=k, metric=metric)
 
 
+INT8_CORPUS = [
+    # (seed, n, dims, m, b, s, c, k, metric)
+    (21, 200, (16,), 2, 3, 64, 2, 5, "dot"),
+    (22, 220, (18, 8), 3, 2, 57, 1, 7, "l2"),  # d % 4 != 0, 2 columns
+]
+
+
+@pytest.mark.parametrize("seed,n,dims,m,b,s,c,k,metric", INT8_CORPUS)
+def test_int8_kernel_parity(seed, n, dims, m, b, s, c, k, metric):
+    """The quantized tier's packed row views (four int8 lanes per word,
+    then the row's dequant scale): kernel vs reference over the same int8
+    replicas — same counters, scores to tolerance, ids up to ties."""
+    from repro.kernels.int8_scan import quantize_rows
+
+    rng = np.random.default_rng(seed)
+    vectors, scalars = _table(rng, n, dims, m)
+    q8 = [quantize_rows(v) for v in vectors]
+    rows = GatherRows.build(tuple(v for v, _ in q8), scalars,
+                            tuple(sc for _, sc in q8))
+    pred_b = stack([_random_pred(rng, m, c) for _ in range(b)])
+    qs = tuple(jnp.asarray(rng.normal(size=(b, d)), jnp.float32)
+               for d in dims)
+    w = jnp.asarray(rng.uniform(0.1, 1.0, (b, len(dims))), jnp.float32)
+    cand = jnp.asarray(_candidates(rng, b, s, n))
+    ids_k, s_k, q_k = gather_score_topk(
+        cand, rows, qs, w, pred_b, k=k, metric=metric, use_kernel=True,
+        interpret=True, block_s=32)
+    ids_r, s_r, q_r = gather_score_topk(
+        cand, rows, qs, w, pred_b, k=k, metric=metric, use_kernel=False)
+    np.testing.assert_array_equal(np.asarray(q_k), np.asarray(q_r))
+    _assert_vs_oracle(ids_k, s_k, np.asarray(ids_r), np.asarray(s_r))
+
+
 def test_kernel_parity_conjunctive_shim():
     """The C=1 conjunctive ``Predicates`` shim must hit the same path as a
     one-clause ``PredicateSet``."""
@@ -201,7 +234,8 @@ def test_all_filtered_out_group():
     cand = _candidates(rng, 2, 64, 120, pad_frac=0.0)
     for use_kernel in (True, False):
         ids, scores, n_qual = gather_score_topk(
-            jnp.asarray(cand), vectors, qs, w, scalars, pred_b, k=5,
+            jnp.asarray(cand), GatherRows.build(vectors, scalars), qs, w,
+            pred_b, k=5,
             metric="dot", use_kernel=use_kernel, interpret=True, block_s=32)
         assert (np.asarray(ids) == -1).all()
         assert (np.asarray(scores) <= NEG / 2).all()
@@ -224,7 +258,8 @@ def test_duplicates_never_crowd_out_distinct_rows():
     pred_b = stack([Predicates.none(1)])
     for use_kernel in (True, False):
         ids, scores, n_qual = gather_score_topk(
-            jnp.asarray(cand), vectors, qs, w, scalars, pred_b, k=k,
+            jnp.asarray(cand), GatherRows.build(vectors, scalars), qs, w,
+            pred_b, k=k,
             metric="dot", use_kernel=use_kernel, interpret=True, block_s=16)
         got = np.asarray(ids)[0]
         assert (got >= 0).all()
@@ -244,7 +279,8 @@ def test_pred_none_skips_masking():
                                       5, "dot")
     for use_kernel in (True, False):
         ids, scores, n_qual = gather_score_topk(
-            jnp.asarray(cand), vectors, qs, w, scalars, None, k=5,
+            jnp.asarray(cand), GatherRows.build(vectors, scalars), qs, w,
+            None, k=5,
             metric="dot", use_kernel=use_kernel, interpret=True, block_s=16)
         np.testing.assert_array_equal(
             np.asarray(n_qual), np.sum(cand >= 0, axis=1))
@@ -312,7 +348,7 @@ def test_search_local_batch_matches_scored_search(tiny_table):
              for c in (1, 2, 4, 1)]
     pred_b = stack(preds)
     ids_l, s_l, n_sc, n_q = ivf.search_local_batch(
-        idx, t.vectors[0], t.scalars, pred_b, q_b,
+        idx, t.gather_rows((0,)), pred_b, q_b,
         nprobe=nprobe, max_scan=max_scan, k=k)
     rs_b = jax.vmap(
         lambda q: similarity(q, t.vectors[0], t.schema.metric))(q_b)
@@ -344,7 +380,7 @@ def test_filter_first_local_batch_matches_sequential(tiny_table):
                 for i in range(t.schema.n_vec))
     w = rng.uniform(0.2, 1.0, (b, t.schema.n_vec)).astype(np.float32)
     ids_l, s_l, n_sc, n_q = flat.filter_first_local_batch(
-        tuple(t.vectors), t.scalars, pred_b, q_b, jnp.asarray(w),
+        t.gather_rows(), pred_b, q_b, jnp.asarray(w),
         k=k, max_candidates=cap, n_vec=t.schema.n_vec,
         metric=t.schema.metric)
     for j in range(b):

@@ -22,6 +22,7 @@ from oracle import NEG, brute_force_topk, similarity_np, tie_aware_recall
 from repro.bench import datasets
 from repro.bench.queries import gen_dnf_workload
 from repro.core.query import ExecutionPlan, SubqueryParams
+from repro.kernels.gather_score import GatherRows
 from repro.vectordb import graph, ivf
 from repro.vectordb.predicates import Predicates, stack
 
@@ -127,7 +128,7 @@ def test_extend_appends_and_reaches_new_rows(sift_fixture):
     recs = []
     for r in range(n0, n0 + 12):
         ids, _, _, _ = graph.search(
-            ext, jnp.asarray(vecs), table.scalars, pred,
+            ext, GatherRows.build((jnp.asarray(vecs),), table.scalars), pred,
             jnp.asarray(vecs[r]), beam_width=16, n_hops=8, k=K)
         m = similarity_np(vecs[r], vecs, metric)
         recs.append(tie_aware_recall(np.asarray(ids), m, K))
@@ -152,10 +153,10 @@ def test_beam_search_kernel_parity(sift_fixture):
     ]
     pred_b = stack(preds)
     ids_j, sc_j, nv_j, nq_j = graph.search_local_batch(
-        g, table.vectors[0], table.scalars, pred_b, q_b,
+        g, table.gather_rows((0,)), pred_b, q_b,
         beam_width=8, n_hops=4, k=K, use_kernel=False)
     ids_k, sc_k, nv_k, nq_k = graph.search_local_batch(
-        g, table.vectors[0], table.scalars, pred_b, q_b,
+        g, table.gather_rows((0,)), pred_b, q_b,
         beam_width=8, n_hops=4, k=K, use_kernel=True, interpret=True)
     assert np.array_equal(np.asarray(ids_j), np.asarray(ids_k))
     np.testing.assert_allclose(np.asarray(sc_j), np.asarray(sc_k),
@@ -181,7 +182,7 @@ def test_single_column_filtered_recall(sift_fixture):
     recs = []
     for r in rng.choice(vecs.shape[0], 10, replace=False):
         q = (vecs[r] + rng.normal(0, 0.02, vecs.shape[1])).astype(np.float32)
-        ids, _, _, _ = graph.search(g, table.vectors[0], table.scalars, pred,
+        ids, _, _, _ = graph.search(g, table.gather_rows((0,)), pred,
                                     jnp.asarray(q), beam_width=16, n_hops=8,
                                     k=K)
         masked = np.where(mask, similarity_np(q, vecs, metric), NEG)
@@ -227,7 +228,7 @@ def test_hard_stratum_graph_beats_ivf_at_equal_budget(sift_fixture):
     for c, q in cases:
         pred = Predicates.from_conditions(3, {0: (float(c), float(c))})
         ids, _, nvis, _ = graph.search(
-            g, table.vectors[0], table.scalars, pred, jnp.asarray(q),
+            g, table.gather_rows((0,)), pred, jnp.asarray(q),
             beam_width=16, n_hops=8, k=K)
         g_rec.append(tie_aware_recall(
             np.asarray(ids), _masked_cluster_scores(table, q, c, metric), K))

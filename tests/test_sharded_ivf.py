@@ -196,6 +196,52 @@ def test_selective_predicate_escalates_to_exact(tiny_table):
         set(np.flatnonzero(masked > -1e29).tolist())
 
 
+def test_starved_iterative_probe_escalates(tiny_table):
+    """An iterative subquery whose shard probe qualifies fewer than k_i
+    rows: the single-device path re-expands nprobe (iterative_scan) and
+    finds rows the first probe missed; each starved shard takes the exact
+    retry, so the sharded path is never below it. The same plan without
+    ``iterative`` keeps the probed result, misses included."""
+    from repro.core.query import MHQ
+    from repro.vectordb.predicates import Predicates
+
+    t = tiny_table
+    idx = _indexes(t)
+    scal = np.asarray(t.scalars)
+    col = next(i for i, c in enumerate(t.schema.scalar_cols)
+               if c.kind == "num")
+    lo, hi = np.quantile(scal[:, col], [0.25, 0.75])  # half the rows
+    rng = np.random.default_rng(7)
+    wl = [MHQ(query_vectors=tuple(
+        jnp.asarray(rng.normal(size=(v.shape[1],)).astype(np.float32))
+        for v in t.vectors), weights=(1.0, 0.0),
+        predicates=Predicates.from_conditions(
+            t.schema.n_scalar, {col: (float(lo), float(hi))}), k=10)
+        for _ in range(4)]
+
+    def plan(it):
+        return ExecutionPlan("index_scan", tuple(
+            SubqueryParams(k_mult=8, nprobe=1, max_scan=t.n_rows,
+                           iterative=it) for _ in range(2)))
+
+    single = BatchedHybridExecutor(t, idx).execute_batch(
+        wl, [plan(True)] * len(wl))
+    bx = BatchedHybridExecutor(t, idx, n_shards=3,
+                               cost_model=CostModel(force=SHARDED_LOCAL))
+    sharded = bx.execute_batch_sharded(wl, [plan(True)] * len(wl))
+    assert bx.escalated == set(range(len(wl)))
+    for q, (ids, _), (ids_s, _) in zip(wl, single, sharded):
+        assert _oracle_recall(t, q, ids_s) == 1.0 >= \
+            _oracle_recall(t, q, ids)
+    # without re-expansion the first probe's misses stand
+    bx = BatchedHybridExecutor(t, idx, n_shards=3,
+                               cost_model=CostModel(force=SHARDED_LOCAL))
+    probed = bx.execute_batch_sharded(wl, [plan(False)] * len(wl))
+    assert not bx.escalated
+    assert np.mean([_oracle_recall(t, q, ids)
+                    for q, (ids, _) in zip(wl, probed)]) < 1.0
+
+
 def test_boundary_trigger_escalates_dominant_shard_only(monkeypatch):
     """The finer escalation trigger (merged-underfill almost never fires —
     other shards pad the merge out, so probe misses in a DOMINANT shard

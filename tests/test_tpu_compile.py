@@ -2,7 +2,8 @@
 
 No chip is attached: ``get_topology_desc`` describes a v5e:2x2 host, and
 each kernel is lowered and compiled by the TPU compiler against one of its
-devices at the widths the deployments use. That catches what interpret
+devices at the widths the deployments use; the mesh-sharded serving
+programs compile against all four. That catches what interpret
 mode cannot — block shapes off the (8, 128) tiling, row copies Mosaic
 refuses, scalar stores into vector refs, VMEM overuse — at no chip time.
 Each compiled program must contain the Mosaic kernel (``tpu_custom_call``).
@@ -16,6 +17,7 @@ same tests, and only the worker that runs them loads it.
 """
 import os
 
+import numpy as np
 import pytest
 
 import jax
@@ -28,11 +30,10 @@ N_SCALARS = 8
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
-    from jax.sharding import SingleDeviceSharding
 
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -44,9 +45,23 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(topo.devices[:4]), ("data",))
 
 
 def _spec(sharding, shape, dtype=jnp.float32):
@@ -61,6 +76,25 @@ def _pred(sharding, b, c):
         lo=_spec(sharding, (b, c, N_SCALARS)),
         hi=_spec(sharding, (b, c, N_SCALARS)),
         clause_valid=_spec(sharding, (b, c), jnp.bool_))
+
+
+def _rows(sharding, dims, *, int8=False, n=N_ROWS):
+    """``GatherRows`` shapes of ``n`` rows: columns of widths ``dims`` and
+    their row views, as ``Table.gather_rows`` lays them out."""
+    from repro.kernels.gather_score import GatherRows
+    from repro.kernels.shapes import round128
+
+    vdt = jnp.int8 if int8 else jnp.float32
+    widths = [round128(-(-d // 4) + 1) if int8 else round128(d)
+              for d in dims]
+    return GatherRows(
+        vectors=tuple(_spec(sharding, (n, d), vdt) for d in dims),
+        scalars=_spec(sharding, (n, N_SCALARS)),
+        scales=tuple(_spec(sharding, (n,)) for _ in dims) if int8 else None,
+        views=tuple(_spec(sharding, (n, 1, w),
+                          jnp.int32 if int8 else jnp.float32)
+                    for w in widths),
+        meta=_spec(sharding, (n, 1, 128)))
 
 
 def _assert_kernel(fn, *args):
@@ -83,19 +117,15 @@ def test_gather_score_blocks_compiles(one_chip, dim, n_cols, int8, k):
     from repro.kernels.gather_score import gather_score_blocks
 
     b, c = 8, 2
-    vdt = jnp.int8 if int8 else jnp.float32
     args = (
         _spec(one_chip, (b, 2 * GATHER_BLOCK_S), jnp.int32),
-        tuple(_spec(one_chip, (N_ROWS, dim), vdt) for _ in range(n_cols)),
+        _rows(one_chip, (dim,) * n_cols, int8=int8),
         tuple(_spec(one_chip, (b, dim)) for _ in range(n_cols)),
         _spec(one_chip, (b, n_cols)),
-        _spec(one_chip, (N_ROWS, N_SCALARS)),
         _spec(one_chip, (b, c, N_SCALARS)),
         _spec(one_chip, (b, c, N_SCALARS)),
         _spec(one_chip, (b, c, N_SCALARS)),
         _spec(one_chip, (b, c)),
-        tuple(_spec(one_chip, (N_ROWS,)) for _ in range(n_cols))
-        if int8 else None,
     )
     _assert_kernel(lambda *a: gather_score_blocks(
         *a, k=k, block_s=GATHER_BLOCK_S, metric="l2" if int8 else "dot",
@@ -114,8 +144,7 @@ def test_beam_search_compiles(one_chip):
     args = (
         _spec(one_chip, (N_ROWS, degree), jnp.int32),
         _spec(one_chip, (GRAPH_ENTRY_POINTS,), jnp.int32),
-        _spec(one_chip, (N_ROWS, 768)),
-        _spec(one_chip, (N_ROWS, N_SCALARS)),
+        _rows(one_chip, (768,)),
         _pred(one_chip, b, 2),
         _spec(one_chip, (b, 768)),
     )
@@ -145,3 +174,55 @@ def test_full_scan_kernels_compile(one_chip, int8):
         _assert_kernel(lambda *a: masked_topk_blocks(
             *a, k=10, metric="l2", interpret=False), _spec(one_chip, (d,)),
             _spec(one_chip, (n, d)), *tail)
+
+
+def _sharded_program(mesh, name):
+    """(fn, args) of one mesh-sharded serving program over part's 200k
+    rows, placed as ``bind_shards(4, mesh=...)`` places the table: rows
+    split over the mesh's data axis, queries and predicates replicated."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core.executor import rrf_union_total
+    from repro.serve.batch import _dense_scores
+    from repro.vectordb.distributed import (
+        sharded_batch_topk, sharded_ivf_topk,
+    )
+
+    b, c, d = 8, 2, 768
+    rows = NamedSharding(mesh, P("data"))
+    repl = NamedSharding(mesh, P())
+    if name == "dense_scores":
+        return (lambda v, q: _dense_scores(v, q, metric="dot"),
+                (_spec(rows, (N_ROWS, d)), _spec(repl, (b, d))))
+    if name == "sharded_batch_topk":
+        return (sharded_batch_topk(mesh, k=10),
+                (_spec(NamedSharding(mesh, P(None, "data")), (b, N_ROWS)),
+                 _spec(rows, (N_ROWS, N_SCALARS)), _pred(repl, b, c)))
+    n_shards, n_clusters, shard_len = 4, 16, N_ROWS // 4
+    subs = ((0, 20, 32, 4, 4096, True), (1, 20, 32, 4, 4096, True))
+    fn = sharded_ivf_topk(n_shards, mesh, subs=subs, k=10, metric="dot",
+                          pad_total=rrf_union_total(40))
+    per_col = lambda shape, dt=jnp.float32: tuple(
+        _spec(rows, (n_shards,) + shape, dt) for _ in subs)
+    return fn, (per_col((n_clusters, d)), per_col((shard_len,), jnp.int32),
+                per_col((n_clusters + 1,), jnp.int32),
+                _rows(rows, (d, d)), _pred(repl, b, c),
+                tuple(_spec(repl, (b, d)) for _ in subs),
+                _spec(repl, (b, 2)))
+
+
+@pytest.mark.parametrize("name", ["dense_scores", "sharded_batch_topk",
+                                  "sharded_ivf_topk"])
+def test_mesh_sharded_programs_compile(four_chips, name, monkeypatch):
+    """The ``--chips 4`` serving programs over a 4-device v5e mesh: the
+    dense route's row-sharded scores and shard_map merge, and the per-shard
+    probing route, whose gather kernel runs on each device's own row block
+    under shard_map. The merges must gather across the mesh. The programs
+    pick the kernel from the backend, so the backend reads as the chip's."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, args = _sharded_program(four_chips, name)
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    if name != "dense_scores":
+        assert "all-gather" in text
+    if name == "sharded_ivf_topk":
+        assert "tpu_custom_call" in text
