@@ -24,6 +24,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.common.spans import span
 from repro.core.data_encoder import DataEncoder, DataEncoderConfig
 from repro.core.executor import EngineCaps, HybridExecutor, PGVECTOR
 from repro.core.query import ExecutionPlan, MHQ, SubqueryParams, default_plan
@@ -93,6 +94,10 @@ class BoomHQ:
         # post-swap jit shapes with REAL traffic before the epoch publish
         self._recent: deque = deque(maxlen=64)
         self._last_batch = 1
+        # batched execution: queries run, and underfill escalation (queries
+        # retried, retry passes, retries that returned more valid rows)
+        self.counts = {"queries_executed": 0, "escalated": 0,
+                       "escalation_passes": 0, "escalation_improved": 0}
 
     # -- offline -------------------------------------------------------------
 
@@ -314,27 +319,31 @@ class BoomHQ:
         elif getattr(self, "_plan_batch_local_jit", None) is None:
             self._build_plan_batch_jit(scored=False)
         from repro.serve.batch import next_bucket
-        b = len(qs)
-        qpad = list(qs) + [qs[0]] * (next_bucket(b) - b)
-        de = self.data_encoder
-        de_args = (de.params, de.edges) if (self.cfg.use_de and de is not None) \
-            else (None, None)
         from repro.vectordb import predicates
-        pred_b = predicates.stack([q.predicates for q in qpad])
-        qv_b = tuple(jnp.stack([q.query_vectors[i] for q in qpad])
-                     for i in range(t.schema.n_vec))
-        args = (
-            self.rewriter.params, de_args, self.qenc._edges, hs,
-            tuple(idxs), tuple(t.vectors), t.scalars,
-            qv_b, pred_b,
-            jnp.asarray([q.weights for q in qpad], jnp.float32),
-            jnp.asarray([float(np.log(q.k)) for q in qpad], jnp.float32),
-            jnp.asarray([q.recall_target for q in qpad], jnp.float32))
-        codes = np.asarray(
-            self._plan_batch_jit(*args, scores_b) if dense
-            else self._plan_batch_local_jit(*args))
-        return [self._apply_skew_guard(self.rewriter.plan_from_codes(c), q)
-                for q, c in zip(qs, codes[:b])]
+        b = len(qs)
+        with span("hq.planner.prepare"):
+            qpad = list(qs) + [qs[0]] * (next_bucket(b) - b)
+            de = self.data_encoder
+            de_args = (de.params, de.edges) \
+                if (self.cfg.use_de and de is not None) else (None, None)
+            pred_b = predicates.stack([q.predicates for q in qpad])
+            qv_b = tuple(jnp.stack([q.query_vectors[i] for q in qpad])
+                         for i in range(t.schema.n_vec))
+            args = (
+                self.rewriter.params, de_args, self.qenc._edges, hs,
+                tuple(idxs), tuple(t.vectors), t.scalars,
+                qv_b, pred_b,
+                jnp.asarray([q.weights for q in qpad], jnp.float32),
+                jnp.asarray([float(np.log(q.k)) for q in qpad], jnp.float32),
+                jnp.asarray([q.recall_target for q in qpad], jnp.float32))
+        with span("hq.planner.sync"):  # the plan program and its one sync
+            codes = np.asarray(
+                self._plan_batch_jit(*args, scores_b) if dense
+                else self._plan_batch_local_jit(*args))
+        with span("hq.planner.decode"):
+            return [self._apply_skew_guard(self.rewriter.plan_from_codes(c),
+                                           q)
+                    for q, c in zip(qs, codes[:b])]
 
     def _build_plan_jit(self):
         fused = self._fused_x if getattr(self, "_fused_x", None) is not None \
@@ -634,32 +643,52 @@ class BoomHQ:
         # groups gather only their candidate budgets (per-group dispatch can
         # still fall back to a per-chunk GEMM when a group wants dense)
         plan_local = self._plan_local(len(queries), cold)
-        scores_b = None if plan_local \
-            else compute_batch_scores(t, queries)
+        scores_b = None
+        if not plan_local:
+            with span("hq.exec.score"):
+                scores_b = compute_batch_scores(t, queries)
         bx = self._batched_executor(cold)
+        self.counts["queries_executed"] += len(queries)
         if self._sharded:
             results = self._execute_batch_sharded(queries, bx, scores_b,
                                                   cold=cold)
         else:
             plans = self.optimize_batch(queries, scores_b=scores_b,
                                         dense=not plan_local, cold=cold)
-            results = bx.execute_batch(queries, plans, scores_b=scores_b)
+            with span("hq.exec.groups"):
+                results = bx.execute_batch(queries, plans, scores_b=scores_b)
+            results = self._escalate(
+                queries, results, bx, scores_b,
+                lambda q: default_plan(q.n_vec, self.engine))
+        if snap is not None and snap.hot_views:
+            with span("hq.exec.merge_hot"):
+                results = self._merge_hot(results, queries, snap)
+        return results
 
+    def _escalate(self, queries: list[MHQ], results: list, bx, scores_b,
+                  retry_plan) -> list:
+        """Underfill escalation: every query that came back with fewer
+        than k valid rows runs again, as one grouped pass, under
+        ``retry_plan(query)``; the better-filled result wins. Counted in
+        ``counts``."""
+        with span("hq.exec.underfill"):  # one host sync per query
             under = [j for j, (ids, _) in enumerate(results)
                      if _n_valid(ids) < queries[j].k]
-            if under:
-                sub = np.asarray(under)
-                retry = bx.execute_batch(
-                    [queries[j] for j in under],
-                    [default_plan(queries[j].n_vec, self.engine)
-                     for j in under],
-                    scores_b=tuple(s[sub] for s in scores_b)
-                    if scores_b is not None else None)
-                for j, (ids2, s2) in zip(under, retry):
-                    if _n_valid(ids2) > _n_valid(results[j][0]):
-                        results[j] = (ids2, s2)
-        if snap is not None and snap.hot_views:
-            results = self._merge_hot(results, queries, snap)
+        if not under:
+            return results
+        self.counts["escalated"] += len(under)
+        self.counts["escalation_passes"] += 1
+        with span("hq.exec.escalate"):
+            sub = np.asarray(under)
+            retry = bx.execute_batch(
+                [queries[j] for j in under],
+                [retry_plan(queries[j]) for j in under],
+                scores_b=tuple(s[sub] for s in scores_b)
+                if scores_b is not None else None)
+            for j, (ids2, s2) in zip(under, retry):
+                if _n_valid(ids2) > _n_valid(results[j][0]):
+                    results[j] = (ids2, s2)
+                    self.counts["escalation_improved"] += 1
         return results
 
     def _merge_hot(self, results, queries: list[MHQ], snap) -> list:
@@ -715,24 +744,14 @@ class BoomHQ:
         same recall contract the single-shard learned path keeps."""
         t = self.table if cold is None else cold.table
         plans = self.optimize_batch(queries, scores_b=scores_b, cold=cold)
-        results = bx.execute_batch_sharded(queries, plans,
-                                           scores_b=scores_b)
-        under = [j for j, (ids, _) in enumerate(results)
-                 if _n_valid(ids) < queries[j].k]
-        if under:
-            sub = np.asarray(under)
-            exact = [ExecutionPlan(
-                "filter_first",
-                tuple(SubqueryParams() for _ in range(queries[j].n_vec)),
-                max_candidates=t.n_rows) for j in under]
-            retry = bx.execute_batch(
-                [queries[j] for j in under], exact,
-                scores_b=tuple(s[sub] for s in scores_b)
-                if scores_b is not None else None)
-            for j, (ids2, s2) in zip(under, retry):
-                if _n_valid(ids2) > _n_valid(results[j][0]):
-                    results[j] = (ids2, s2)
-        return results
+        with span("hq.exec.groups"):
+            results = bx.execute_batch_sharded(queries, plans,
+                                               scores_b=scores_b)
+        return self._escalate(
+            queries, results, bx, scores_b,
+            lambda q: ExecutionPlan(
+                "filter_first", tuple(SubqueryParams() for _ in range(q.n_vec)),
+                max_candidates=t.n_rows))
 
     def _batched_executor(self, cold=None):
         """Executor bound to the serving state — the façade's fields, or a

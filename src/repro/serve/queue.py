@@ -23,12 +23,17 @@ missing front half of the serving pipeline:
     accepting arrivals mid-execution.
 
 Dispositions and latency percentiles land in the shared ``ServeReport``
-(``n_timed_out``, ``p50_ms``/``p99_ms``).
+(``n_timed_out``, ``p50_ms``/``p99_ms``). The front end counts its cuts,
+by reason, and their queue wait in ``counts``; the drainer's waits
+(``hq.frontend.cut_wait``, ``hq.frontend.await_arrival``) and the
+resolution of a finished batch (``hq.frontend.resolve``) are host spans
+(``repro.common.spans``).
 """
 from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import contextvars
 import dataclasses
 import functools
 import time
@@ -36,6 +41,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.common.spans import BATCH, span
 from repro.core.executor import recall_at_k
 from repro.core.query import MHQ
 from repro.serve.batch import ServeReport
@@ -60,6 +66,8 @@ class ServeRequest:
     status: str = PENDING  # PENDING | OK | TIMED_OUT | FAILED
     result: Optional[tuple] = None  # (ids, scores) when status == OK
     done: float = 0.0
+    cut: Optional[float] = None  # clock instant its batch was cut
+    batch: Optional[int] = None  # sequence number of that batch
     cache_hit: bool = False  # resolved by the semantic cache, zero scan cost
     # tiered serving: the immutable (epoch, hot, cold) snapshot stamped on
     # the whole batch at CUT time — every request in a batch shares one, so
@@ -92,6 +100,17 @@ class BatchFormer:
         self.snapshot_fn = snapshot_fn
         self._pending: list[ServeRequest] = []
         self._seq = 0
+        # requests cut, their summed queue wait (cut - arrival, seconds)
+        # and batches by what cut them; AsyncServingEngine.counts
+        self.counts = {"requests_cut": 0, "queue_wait_s": 0.0,
+                       "batches_full": 0, "batches_age": 0,
+                       "batches_flush": 0}
+
+    @property
+    def n_cut(self) -> int:
+        """Batches cut so far: the next batch's sequence number."""
+        c = self.counts
+        return c["batches_full"] + c["batches_age"] + c["batches_flush"]
 
     def __len__(self) -> int:
         return len(self._pending)
@@ -139,20 +158,31 @@ class BatchFormer:
         Expiry runs first (expired requests never enter a batch); then a
         batch of the OLDEST ≤ ``batch_size`` requests cuts when the queue
         is full, the oldest request aged past ``max_wait``, or ``flush``
-        forces the remainder out."""
+        forces the remainder out. Each request of the batch is stamped
+        with ``now`` (``cut``) and the batch's number, and ``counts`` take
+        the cut, its reason and its queue wait."""
         now = self.clock() if now is None else now
         expired = self.expire(now)
-        batch = None
-        if self._pending and (
-                len(self._pending) >= self.batch_size
-                or now - self._pending[0].arrival >= self.max_wait
-                or flush):
-            batch = self._pending[: self.batch_size]
-            self._pending = self._pending[self.batch_size:]
-            if self.snapshot_fn is not None:
-                snap = self.snapshot_fn()  # snapshot-at-cut: one per batch
-                for r in batch:
-                    r.snapshot = snap
+        if not self._pending:
+            return None, expired
+        if len(self._pending) >= self.batch_size:
+            why = "batches_full"
+        elif now - self._pending[0].arrival >= self.max_wait:
+            why = "batches_age"
+        elif flush:
+            why = "batches_flush"
+        else:
+            return None, expired
+        batch = self._pending[: self.batch_size]
+        self._pending = self._pending[self.batch_size:]
+        snap = self.snapshot_fn() if self.snapshot_fn is not None else None
+        for r in batch:
+            r.cut, r.batch = now, self.n_cut
+            if snap is not None:
+                r.snapshot = snap  # snapshot-at-cut: one per batch
+        self.counts[why] += 1
+        self.counts["requests_cut"] += len(batch)
+        self.counts["queue_wait_s"] += sum(r.cut - r.arrival for r in batch)
         return batch, expired
 
     def drain(self) -> list[ServeRequest]:
@@ -244,6 +274,9 @@ class AsyncServingEngine:
         self.bq = boomhq
         self.former = BatchFormer(batch_size=batch_size, max_wait=max_wait,
                                   clock=clock)
+        # the front end's counters: cuts by reason, requests cut and their
+        # summed queue wait (``BatchFormer.counts``)
+        self.counts = self.former.counts
         # optional serve.semcache.SemanticCache consulted at submit time:
         # hits resolve immediately (zero scan cost), misses populate after
         # their batch executes, stamped with the batch snapshot's token
@@ -389,12 +422,20 @@ class AsyncServingEngine:
                 await self._execute(batch)
                 continue  # queue may already hold the next full batch
             nxt = self.former.next_event()
-            try:
-                wait = None if nxt is None \
-                    else max(1e-4, nxt - self.clock())
-                await asyncio.wait_for(self._event.wait(), wait)
-            except asyncio.TimeoutError:
-                pass
+            # a pending request waits for its cut (age or deadline); with
+            # none pending the drainer waits for an arrival. The span is
+            # closed however the wait ends, a cancelling stop() included
+            if nxt is None:
+                waiting = span("hq.frontend.await_arrival")
+            else:
+                waiting = span("hq.frontend.cut_wait", self.former.n_cut)
+            with waiting:
+                try:
+                    wait = None if nxt is None \
+                        else max(1e-4, nxt - self.clock())
+                    await asyncio.wait_for(self._event.wait(), wait)
+                except asyncio.TimeoutError:
+                    pass
             self._event.clear()
 
     async def _execute(self, batch: list[ServeRequest]) -> None:
@@ -422,7 +463,10 @@ class AsyncServingEngine:
                 self.bq.execute_batch, queries, snapshot=batch[0].snapshot)
         else:
             run = functools.partial(self.bq.execute_batch, queries)
-        exec_fut = loop.run_in_executor(self._pool, run)
+        # the worker's spans carry the batch's number (common.spans)
+        ctx = contextvars.copy_context()
+        ctx.run(BATCH.set, batch[0].batch)
+        exec_fut = loop.run_in_executor(self._pool, ctx.run, run)
         try:
             results = await asyncio.shield(exec_fut)
         except asyncio.CancelledError:
@@ -449,21 +493,23 @@ class AsyncServingEngine:
             return
         now = self.clock()
         self._n_batches += 1
-        token = None
-        if self.semcache is not None:
-            snap = batch[0].snapshot
-            # stamp entries with the token of the state the batch actually
-            # executed under (its cut-time snapshot), not the current one —
-            # an epoch swap mid-flight must leave these entries born stale
-            token = (snap.epoch, snap.n_rows) if snap is not None \
-                else (0, self.bq.table.n_rows)
-        for r, res in zip(batch, results):
-            r.status = OK
-            r.result = res
-            r.done = now
-            if token is not None:
-                self.semcache.insert(r.query, token, res[0], res[1])
-            self._finish(r)
+        with span("hq.frontend.resolve", batch[0].batch):
+            token = None
+            if self.semcache is not None:
+                snap = batch[0].snapshot
+                # stamp entries with the token of the state the batch
+                # actually executed under (its cut-time snapshot), not the
+                # current one — an epoch swap mid-flight must leave these
+                # entries born stale
+                token = (snap.epoch, snap.n_rows) if snap is not None \
+                    else (0, self.bq.table.n_rows)
+            for r, res in zip(batch, results):
+                r.status = OK
+                r.result = res
+                r.done = now
+                if token is not None:
+                    self.semcache.insert(r.query, token, res[0], res[1])
+                self._finish(r)
 
     def _resolve_expired(self, expired: list[ServeRequest]) -> None:
         for r in expired:

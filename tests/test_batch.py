@@ -557,6 +557,36 @@ def test_unfitted_execute_batch_uses_default_plans():
         assert_results_match(ids_s, scores_s, ids, scores)
 
 
+@pytest.mark.parametrize("n_shards", [1, 2])
+def test_underfill_escalation_counts(n_shards):
+    """A query qualifying fewer than k rows under filter_first is retried
+    once; its retry finds no row filter_first had not already returned.
+    The single-device and the sharded path count it alike."""
+    import dataclasses
+
+    table = datasets.make("part", rows=900, seed=4)
+    m = table.schema.n_scalar
+    wl = queries.gen_workload(table, 2, n_vec_used=2, seed=7)
+    size = float(np.asarray(table.scalars)[0, 2])  # unique per row
+    short = dataclasses.replace(
+        wl[0], predicates=Predicates.from_conditions(m, {2: (size, size)}))
+    full = dataclasses.replace(wl[1], predicates=Predicates.none(m))
+    bq = BoomHQ(table, BoomHQConfig(
+        n_clusters=8, use_de=False, graph_degree=0,
+        rewriter=RewriterConfig(steps=10, refine_columns=False)))
+    if n_shards > 1:
+        bq.bind_shards(n_shards)
+    bq.optimize_batch = lambda qs, **kw: [
+        ExecutionPlan("filter_first",
+                      tuple(SubqueryParams() for _ in range(q.n_vec)))
+        for q in qs]
+    results = bq.execute_batch([short, full])
+    assert int(np.sum(np.asarray(results[0][0]) >= 0)) == 1
+    assert int(np.sum(np.asarray(results[1][0]) >= 0)) == full.k
+    assert bq.counts == {"queries_executed": 2, "escalated": 1,
+                         "escalation_passes": 1, "escalation_improved": 0}
+
+
 def test_sharded_serving_engine_matches_ground_truth():
     """ServingEngine over a bind_shards-bound BoomHQ with the cost model
     pinned to the EXACT sharded scan: every served result is the exact

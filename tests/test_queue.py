@@ -331,3 +331,118 @@ def test_compaction_failure_surfaces_at_drain():
     with pytest.raises(RuntimeError, match="compaction broke"):
         sched.drain()
     assert tiered.n_calls == 1
+
+
+def test_former_stamps_cut_and_engine_counts_reasons():
+    """Every request of a cut batch carries the cut instant and the batch's
+    number; the engine counts each cut by its reason (full, age, flush)
+    and sums queue wait (cut - arrival) exactly, under a fake clock."""
+    clock = FakeClock()
+
+    class Recorder:
+        def execute_batch(self, qs):
+            return [(np.asarray([0]), np.asarray([0.0]))] * len(qs)
+
+    async def main():
+        eng = AsyncServingEngine(Recorder(), batch_size=2, max_wait=1.0,
+                                 clock=clock)
+        await eng.start()
+        f = eng.former
+        a, b = f.submit("a"), f.submit("b")
+        clock.advance(0.25)
+        full, _ = f.poll()  # two pending: cut on full at 0.25
+        clock.advance(0.25)
+        c = f.submit("c")  # arrives at 0.5
+        assert f.poll(now=1.0) == (None, [])  # aged 0.5 of 1.0
+        clock.advance(1.25)
+        aged, _ = f.poll()  # cut on age at 1.75
+        d = f.submit("d")
+        clock.advance(0.5)
+        flushed, _ = f.poll(flush=True)  # cut by flush at 2.25
+        for batch in (full, aged, flushed):
+            await eng._execute(batch)
+        await eng.stop(flush=False)
+        return eng, (a, b, c, d), (full, aged, flushed)
+
+    eng, (a, b, c, d), (full, aged, flushed) = asyncio.run(main())
+    assert full == [a, b] and aged == [c] and flushed == [d]
+    assert [r.cut for r in (a, b, c, d)] == [0.25, 0.25, 1.75, 2.25]
+    assert [r.batch for r in (a, b, c, d)] == [0, 0, 1, 2]
+    assert eng.counts == {"requests_cut": 4,
+                          "queue_wait_s": 0.25 + 0.25 + 1.25 + 0.5,
+                          "batches_full": 1, "batches_age": 1,
+                          "batches_flush": 1}
+    assert eng.counts is eng.former.counts
+    assert eng._n_batches == 3
+
+
+@pytest.mark.parametrize("pending", [False, True])
+def test_cancelled_drainer_leaves_no_span_open(monkeypatch, pending):
+    """stop(flush=False) cancels the drainer inside its wait: the wait's
+    span (for an arrival, or for a pending request's cut) is exited."""
+    import repro.serve.queue as queue_mod
+
+    opened, closed = [], []
+
+    class Recorded:
+        def __init__(self, name, batch=None):
+            self.name = name
+
+        def __enter__(self):
+            opened.append(self.name)
+
+        def __exit__(self, *exc):
+            closed.append(self.name)
+
+    monkeypatch.setattr(queue_mod, "span", Recorded)
+
+    class Never:
+        def execute_batch(self, qs):
+            raise AssertionError("nothing is cut")
+
+    async def main():
+        eng = AsyncServingEngine(Never(), batch_size=8, max_wait=60.0)
+        await eng.start()
+        if pending:
+            waiter = asyncio.ensure_future(eng.submit("q"))
+        await asyncio.sleep(0.05)  # the drainer is waiting
+        await eng.stop(flush=False)
+        if pending:
+            with pytest.raises(asyncio.CancelledError):
+                await waiter
+
+    asyncio.run(main())
+    want = "hq.frontend.cut_wait" if pending else "hq.frontend.await_arrival"
+    assert opened and opened[-1] == want
+    assert sorted(opened) == sorted(closed)
+
+
+def test_worker_call_carries_its_batch_number():
+    """The worker thread's call of a cut batch sees that batch's number in
+    ``spans.BATCH``, so its spans share it with the event loop's; outside
+    a formed batch there is none."""
+    from repro.common import spans
+
+    clock = FakeClock()
+    seen = []
+
+    class Recorder:
+        def execute_batch(self, qs):
+            seen.append(spans.BATCH.get())
+            return [(np.asarray([0]), np.asarray([0.0]))] * len(qs)
+
+    async def main():
+        eng = AsyncServingEngine(Recorder(), batch_size=1, max_wait=1.0,
+                                 clock=clock)
+        await eng.start()
+        cut = []
+        for q in ("a", "b", "c"):
+            eng.former.submit(q)
+            batch, _ = eng.former.poll()
+            cut.append(batch[0].batch)
+            await eng._execute(batch)
+        await eng.stop(flush=False)
+        return cut
+
+    assert asyncio.run(main()) == seen == [0, 1, 2]
+    assert spans.BATCH.get() is None
