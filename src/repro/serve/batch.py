@@ -398,9 +398,7 @@ def _gather_rerank_batch(rows_b, rows, q_b, w_b, *, k, metric):
 @partial(jax.jit, static_argnames=("size",))
 def _qualifying_rows_batch(mask_b, *, size):
     """(B, n) bool masks -> (B, size) qualifying row ids, -1 padded."""
-    return jax.vmap(
-        lambda m: jnp.nonzero(m, size=size, fill_value=-1)[0]
-    )(mask_b).astype(jnp.int32)
+    return jax.vmap(lambda m: flat.compact_rows(m, size, -1))(mask_b)
 
 
 NEG = -1e30
@@ -485,6 +483,9 @@ class BatchedHybridExecutor:
         # shard-subset retry — benchmarks segment the probe-served tier
         # from the escalation tax with this; callers may clear it
         self.escalated: set = set()
+        # real (unpadded) queries of filter-first chunks, by the method
+        # their candidate compaction took (flat.compaction_method)
+        self.counts = {"ff_rows_search": 0, "ff_rows_scatter": 0}
         self._seq = HybridExecutor(table, indexes, engine, graphs=graphs)
 
     def legalize(self, plan: ExecutionPlan) -> ExecutionPlan:
@@ -980,6 +981,9 @@ class BatchedHybridExecutor:
                                       prefer_dense=scores_b is not None,
                                       precision=precision)
         pred_b, qv_b, w_b = self._stack_inputs(qs, bb)
+        if key[0] == "ff":
+            method = flat.compaction_method(t.n_rows, key[3])
+            self.counts[f"ff_rows_{method}"] += len(qs)
 
         if path == CANDIDATE_LOCAL:
             out_ids, out_scores = self._run_chunk_local(

@@ -242,6 +242,25 @@ def test_batched_executor_filter_first_group(exec_setup):
         assert_results_match(ids_s, scores_s, ids_b, scores_b)
 
 
+def test_filter_first_chunks_count_their_compaction(exec_setup):
+    """Every real filter-first query is counted under the method its
+    compaction took; the chunk's padding is not, and the dispatcher's
+    path counts gain no keys."""
+    t, seq, _ = exec_setup
+    bx = BatchedHybridExecutor(t, seq.indexes)
+    wl = queries.gen_workload(t, 3, n_vec_used=2, seed=5)  # padded to 4
+    plan = ExecutionPlan("filter_first",
+                         tuple(SubqueryParams() for _ in range(2)),
+                         max_candidates=64)
+    assert flat.compaction_method(t.n_rows, 64) == "search"
+    batched = bx.execute_batch(wl, [plan] * len(wl))
+    assert bx.counts == {"ff_rows_search": 3, "ff_rows_scatter": 0}
+    assert set(bx.dispatcher.counts) <= {"dense", "candidate_local"}
+    for q, (ids_b, scores_b) in zip(wl, batched):
+        ids_s, scores_s = seq.execute(q, plan)
+        assert_results_match(ids_s, scores_s, ids_b, scores_b)
+
+
 def test_batched_executor_parity_mixed_clause_counts(exec_setup):
     """Satellite: batched vs sequential on a batch mixing conjunctive (C=1)
     and DNF (C∈{2,4}) predicates — groups split per clause bucket, every
